@@ -3,45 +3,45 @@
 The engines are shape-static by design (pow-2 bucket padding, fixed
 chunk shapes), so across processes the same programs recompile from
 scratch — on TPU a cold compile of the full mixed pipeline costs tens
-of seconds (the round-1 config-⑤ timeout). Enabling JAX's persistent
-compilation cache makes every compile after the first process-lifetime
-one a disk hit.
+of seconds. Enabling JAX's persistent compilation cache makes every
+compile after the first process-lifetime one a disk hit.
 
 Call :func:`enable` before the first jit execution (bench.py, the
-tools, and tests/conftest.py do). Opt out with CAP_TPU_COMPILE_CACHE=0
-or redirect with CAP_TPU_COMPILE_CACHE=/path.
+tools, the fleet worker, chip_smoke.py and tests/conftest.py do).
+
+Where the cache lives:
+
+- ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself; this module
+  sets no directory.
+- otherwise: ``<checkout>/.jax_cache`` — a fixed path (the path is part
+  of the cache key, so it must never move between runs), listed in
+  ``.gitignore`` and ``.chiprunignore``.
 """
 
 from __future__ import annotations
 
 import os
 
-_DEFAULT_DIR = os.path.join(os.path.expanduser("~"), ".cache",
-                            "cap_tpu", "xla")
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
 _enabled = False
 
 
-def enable(cache_dir: str | None = None) -> str | None:
-    """Idempotently enable the persistent compilation cache.
-
-    Returns the cache directory, or None when disabled via env.
-    """
+def enable() -> str:
+    """Idempotently enable the persistent compilation cache; returns
+    its directory."""
     global _enabled
-    env = os.environ.get("CAP_TPU_COMPILE_CACHE")
-    if env in ("0", "false", "no"):
-        return None
-    if cache_dir is None:
-        cache_dir = env if env else _DEFAULT_DIR
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    path = env or DEFAULT_DIR
     if _enabled:
-        return cache_dir
-    try:
-        os.makedirs(cache_dir, exist_ok=True)
-        import jax
+        return path
+    import jax
 
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        _enabled = True
-        return cache_dir
-    except Exception:  # noqa: BLE001 - cache is best-effort
-        return None
+    if not env:
+        os.makedirs(path, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    _enabled = True
+    return path

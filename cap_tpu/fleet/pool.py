@@ -52,6 +52,9 @@ class FleetError(CapError):
     default_message = "fleet error"
 
 
+# Keyset specs whose workers run a real device engine (worker_main).
+DEVICE_SPECS = ("jwks:", "jwks-url:", "oidc:")
+
 # Worker lifecycle states.
 STARTING = "starting"
 READY = "ready"
@@ -83,6 +86,11 @@ class WorkerHandle:
         # None while starting) — what actually runs, stale-.so
         # fallback included.
         self.transport: Optional[str] = None
+        # Platform and device ids JAX gave the worker, from its ready
+        # line ("none" for workers that never touch JAX).
+        self.platform: Optional[str] = None
+        self.devices: Optional[str] = None
+        self.chips: Optional[str] = None
         # Latest collected crash/drain postmortem (obs.postmortem doc)
         # and the checkpoint file the worker writes into.
         self.postmortem: Optional[dict] = None
@@ -110,11 +118,14 @@ class WorkerPool:
     keyset_spec: passed to every worker (``worker_main.make_keyset``).
     placements: explicit list, or None → ``single_owner_placement(
     n_workers, n_devices or n_workers, platform)``.
+    platform: ``"tpu"`` or ``"cpu"``; required for real keyset specs
+    (``jwks:``/``jwks-url:``/``oidc:``), ``"cpu"`` for stub workers.
     """
 
     def __init__(self, n_workers: int, keyset_spec: str = "stub",
                  placements: Optional[List[WorkerPlacement]] = None,
-                 n_devices: Optional[int] = None, platform: str = "cpu",
+                 n_devices: Optional[int] = None,
+                 platform: Optional[str] = None,
                  host: str = "127.0.0.1",
                  target_batch: int = 4096, max_wait_ms: float = 2.0,
                  max_batch: int = 32768,
@@ -131,6 +142,15 @@ class WorkerPool:
                  peer_fill_attempts: int = 50,
                  autoscale: Optional[dict] = None):
         if placements is None:
+            if platform is None:
+                if keyset_spec.startswith(DEVICE_SPECS):
+                    # A real engine must be told where it runs: the
+                    # pool never guesses "cpu" for it, and never
+                    # imports jax itself to find out.
+                    raise FleetError(
+                        f"keyset {keyset_spec.split(':')[0]}: needs an "
+                        "explicit platform= ('tpu' or 'cpu')")
+                platform = "cpu"
             placements = single_owner_placement(
                 n_workers, n_devices if n_devices is not None else n_workers,
                 platform=platform)
@@ -561,6 +581,13 @@ class WorkerPool:
         with self._lock:
             return {h.worker_id: h.serve_chain for h in self._handles}
 
+    def device_report(self) -> Dict[int, Tuple[Optional[str], ...]]:
+        """worker_id → (platform, JAX device ids, host chips) from each
+        ready line (chips: TPU workers only)."""
+        with self._lock:
+            return {h.worker_id: (h.platform, h.devices, h.chips)
+                    for h in self._handles}
+
     def transports(self) -> Dict[int, Optional[str]]:
         """worker_id → transport capability from the ready line
         ("shm" / "socket"; None while starting) — fleet transport
@@ -773,6 +800,9 @@ class WorkerPool:
         epoch = None
         serve_chain = None
         transport = None
+        platform = None
+        devices = None
+        chips = None
         try:
             while time.monotonic() < deadline:
                 line = proc.stdout.readline()
@@ -791,8 +821,25 @@ class WorkerPool:
                             serve_chain = v
                         elif k == "transport":
                             transport = v
+                        elif k == "platform":
+                            platform = v
+                        elif k == "devices":
+                            devices = v
+                        elif k == "chips":
+                            chips = v
                     break
         except (OSError, ValueError):
+            port = None
+        if port is not None and platform not in (
+                None, "none", h.placement.platform):
+            # The worker got a different device than its placement
+            # names: refuse it rather than serve off the wrong chip.
+            print(f"cap_tpu.fleet: worker {h.worker_id} came up on "
+                  f"platform={platform}, placement says "
+                  f"{h.placement.platform}; refusing it",
+                  file=sys.stderr, flush=True)
+            telemetry.count("fleet.platform_mismatch")
+            proc.kill()
             port = None
         with self._lock:
             if h.proc is not proc or self._closed.is_set():
@@ -807,6 +854,9 @@ class WorkerPool:
                 h.key_epoch = epoch
                 h.serve_chain = serve_chain
                 h.transport = transport
+                h.platform = platform
+                h.devices = devices
+                h.chips = chips
                 h.state = READY
                 h.peer_fill_pending = self._peer_fill
                 h.peer_fill_attempts = 0

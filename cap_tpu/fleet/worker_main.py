@@ -253,20 +253,38 @@ def make_keyset(spec: str):
 
 
 def _configure_devices() -> None:
-    """Apply the placement env to jax BEFORE first backend use."""
+    """Apply the placement env to jax BEFORE first backend use, and
+    turn the persistent compile cache on so a respawned worker does
+    not compile cold."""
+    import jax
+
+    from .. import compile_cache
+
     n_cpu = int(os.environ.get("CAP_FLEET_CPU_DEVICES", "0") or 0)
     if n_cpu:
-        import jax
-
         jax.config.update("jax_platforms", "cpu")
-        try:
-            jax.config.update("jax_num_cpu_devices", n_cpu)
-        except AttributeError:
-            os.environ["XLA_FLAGS"] = (
-                os.environ.get("XLA_FLAGS", "")
-                + f" --xla_force_host_platform_device_count={n_cpu}")
-    # platform="tpu": TPU_VISIBLE_DEVICES is already in the env and
-    # libtpu reads it at backend init — nothing to do here.
+        jax.config.update("jax_num_cpu_devices", n_cpu)
+    # platform="tpu": libtpu reads the chip bounds and visible chips
+    # from the placement env at backend init — nothing to do here.
+    compile_cache.enable()
+
+
+def _device_fields() -> str:
+    """Ready-line fields naming the devices JAX actually gave this
+    process (``platform=none`` when it never touched JAX: the stub
+    and router keysets use no device)."""
+    if "jax" not in sys.modules:
+        return " platform=none"
+    import jax
+
+    devs = jax.local_devices()
+    out = (f" platform={devs[0].platform}"
+           f" devices={','.join(str(d.id) for d in devs)}")
+    if devs[0].platform == "tpu":
+        # JAX numbers a one-chip slice's device 0 whichever chip it
+        # is; the host chip comes from the placement libtpu honored.
+        out += f" chips={os.environ.get('TPU_VISIBLE_CHIPS', 'all')}"
+    return out
 
 
 def main(argv=None) -> int:
@@ -399,6 +417,7 @@ def main(argv=None) -> int:
           + f" serve_chain={worker.serve_chain}"
           + f" transport={worker.transport}"
           + f" tel={int(telemetry.active() is not None)}"
+          + _device_fields()
           + (f" frontdoor_chain={fd_chain}" if fd_chain else ""),
           flush=True)
 

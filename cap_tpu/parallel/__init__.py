@@ -16,8 +16,15 @@ batched-verify workload:
 Verdict reduction (count of valid tokens) rides a ``psum`` over ``dp``.
 """
 
-from .mesh import (  # noqa: F401
-    make_mesh,
-    sharded_rs256_verify,
-    sharded_verify_step,
-)
+# Lazy: ``parallel.place`` (fleet placement) must import without jax —
+# a fleet parent that imported it would be one step from holding the
+# chip its workers need.
+_MESH_EXPORTS = ("make_mesh", "sharded_rs256_verify", "sharded_verify_step")
+
+
+def __getattr__(name):
+    if name in _MESH_EXPORTS:
+        from . import mesh
+
+        return getattr(mesh, name)
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

@@ -40,9 +40,14 @@ class WorkerPlacement:
     process, expressed as subprocess environment rather than runtime
     cooperation, so ownership is enforced by process isolation:
 
-    - ``platform="tpu"``: ``TPU_VISIBLE_DEVICES`` restricts the child
-      to its chip group (libtpu refuses a chip another process holds —
-      the single-owner invariant is also enforced by the hardware
+    - ``platform="tpu"``: the child sees only its chips and is its
+      own one-process slice — ``TPU_VISIBLE_CHIPS`` names the chips,
+      ``TPU_CHIPS_PER_PROCESS_BOUNDS``/``TPU_PROCESS_BOUNDS`` make the
+      group a whole slice (which is also what lets several libtpu
+      loads share one host), and ``TPU_PROCESS_PORT``/
+      ``TPU_PROCESS_ADDRESSES`` give each process its own runtime
+      port (libtpu refuses a chip another process holds — the
+      single-owner invariant is also enforced by the hardware
       runtime);
     - ``platform="cpu"`` (this container, tests, dry-runs): the child
       gets its OWN virtual-device world (``JAX_PLATFORMS=cpu`` plus a
@@ -60,12 +65,34 @@ class WorkerPlacement:
         out = {"CAP_FLEET_WORKER_ID": str(self.worker_id),
                "CAP_FLEET_DEVICE_GROUP": ids}
         if self.platform == "tpu":
-            out["JAX_PLATFORMS"] = "tpu"
-            out["TPU_VISIBLE_DEVICES"] = ids
+            port = str(_TPU_PORT_BASE + self.worker_id)
+            out.update({
+                "JAX_PLATFORMS": "tpu",
+                "TPU_VISIBLE_CHIPS": ids,
+                "TPU_CHIPS_PER_PROCESS_BOUNDS": _chip_bounds(
+                    len(self.device_ids)),
+                "TPU_PROCESS_BOUNDS": "1,1,1",
+                "TPU_PROCESS_PORT": port,
+                "TPU_PROCESS_ADDRESSES": f"localhost:{port}",
+            })
         else:
             out["JAX_PLATFORMS"] = "cpu"
             out["CAP_FLEET_CPU_DEVICES"] = str(len(self.device_ids))
         return out
+
+
+# Per-worker libtpu runtime port: base + worker id keeps the ports of
+# one host's one-chip processes distinct.
+_TPU_PORT_BASE = 8476
+
+
+def _chip_bounds(n_chips: int) -> str:
+    """TPU_CHIPS_PER_PROCESS_BOUNDS for a group of ``n_chips`` chips of
+    a v5e host (2x2 chips)."""
+    bounds = {1: "1,1,1", 2: "1,2,1", 4: "2,2,1"}
+    if n_chips not in bounds:
+        raise PlacementError(f"no TPU chip bounds for {n_chips} chips")
+    return bounds[n_chips]
 
 
 def single_owner_placement(n_workers: int, n_devices: int,
@@ -156,6 +183,45 @@ def shard_batch(mesh, arr):
 
     spec = PartitionSpec(batch_axis(mesh), *([None] * (arr.ndim - 1)))
     return jax.device_put(arr, NamedSharding(mesh, spec))
+
+
+_sharded_jit = None
+
+
+def _sharded_body(rec, *tables, impl, mesh, static):
+    from functools import partial
+
+    import jax
+    from jax.sharding import PartitionSpec
+
+    ax = batch_axis(mesh)
+    return jax.shard_map(
+        partial(impl, **dict(static)), mesh=mesh,
+        in_specs=(PartitionSpec(ax),) + (PartitionSpec(),) * len(tables),
+        out_specs=PartitionSpec(ax), check_vma=False)(rec, *tables)
+
+
+def run_batch_sharded(impl, mesh, rec, tables, static: dict):
+    """``impl(rec, *tables, **static)`` over a mesh: the record split
+    along the batch axis, the tables replicated, and the per-token
+    program run by every device on its own rows (``shard_map``).
+
+    GSPMD cannot partition the Pallas kernels inside the verify
+    programs ("Mosaic kernels cannot be automatically partitioned"),
+    and need not: tokens are independent, so each device's shard is a
+    complete small batch. Outputs are per-token arrays (or tuples of
+    them), sharded the same way.
+    """
+    global _sharded_jit
+    import jax
+
+    if _sharded_jit is None:
+        _sharded_jit = jax.jit(_sharded_body,
+                               static_argnames=("impl", "mesh", "static"))
+    tables = jax.tree_util.tree_map(lambda a: replicated(mesh, a),
+                                    tuple(tables))
+    return _sharded_jit(shard_batch(mesh, rec), *tables, impl=impl,
+                        mesh=mesh, static=tuple(sorted(static.items())))
 
 
 def replicated(mesh, arr):

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import datetime
 import json
+import os
 from contextlib import contextmanager
 from typing import Any, Dict, Optional, Tuple
 
@@ -35,7 +36,19 @@ _HASH = {
 
 
 def generate_keys(alg: str = algs.ES256, rsa_bits: int = 2048):
-    """Generate a (private, public) key pair suitable for ``alg``."""
+    """Generate a (private, public) key pair suitable for ``alg``.
+
+    ML-DSA and SLH-DSA pairs come from the repo's own fixture signers
+    (``cap_tpu.tpu.mldsa`` / ``slhdsa`` keygen from a random seed).
+    """
+    if alg in algs.MLDSA_ALGORITHMS:
+        from .tpu import mldsa
+
+        return mldsa.keygen(alg, os.urandom(32))
+    if alg in algs.SLHDSA_ALGORITHMS:
+        from .tpu import slhdsa
+
+        return slhdsa.keygen(alg, os.urandom(32))
     if alg in (algs.RS256, algs.RS384, algs.RS512,
                algs.PS256, algs.PS384, algs.PS512):
         priv = rsa.generate_private_key(public_exponent=65537, key_size=rsa_bits)
@@ -63,7 +76,7 @@ def sign_jwt(priv, alg: str, claims: Dict[str, Any],
         + b64url_encode(json.dumps(claims, separators=(",", ":")).encode())
     ).encode("ascii")
 
-    hash_cls = _HASH[algs.HASH_FOR_ALG[alg]]
+    hash_cls = _HASH.get(algs.HASH_FOR_ALG.get(alg, ""))
     if alg in (algs.RS256, algs.RS384, algs.RS512):
         sig = priv.sign(signing_input, padding.PKCS1v15(), hash_cls())
     elif alg in (algs.PS256, algs.PS384, algs.PS512):
@@ -78,7 +91,7 @@ def sign_jwt(priv, alg: str, claims: Dict[str, Any],
         der = priv.sign(signing_input, ec.ECDSA(hash_cls()))
         r, s = decode_dss_signature(der)
         sig = r.to_bytes(coord, "big") + s.to_bytes(coord, "big")
-    elif alg == algs.EdDSA:
+    elif alg == algs.EdDSA or alg in algs.PQ_ALGORITHMS:
         sig = priv.sign(signing_input)
     else:
         raise ValueError(f"unsupported alg {alg!r}")
@@ -111,7 +124,6 @@ def sign_unique_jwts(signers, n: int, ttl: float = 86400.0):
     signers: [(private_key, alg, kid), ...] cycled round-robin; signing
     runs across threads (OpenSSL releases the GIL).
     """
-    import os
     from concurrent.futures import ThreadPoolExecutor
 
     base = default_claims(ttl=ttl)
@@ -125,25 +137,31 @@ def sign_unique_jwts(signers, n: int, ttl: float = 86400.0):
         return list(ex.map(sign, range(n), chunksize=256))
 
 
-def headline_fixtures(n_unique: int):
-    """The BASELINE.json north-star workload: a 16-key JWKS (8×RSA-2048
-    + 8×P-256) and n_unique UNIQUE mixed RS256/ES256 tokens.
-
-    Shared by bench.py and tools/bench_serve.py so the offline and
-    serving benchmarks can never desynchronize their key mix.
-    """
-    from .jwt import algs
+def headline_keys():
+    """The BASELINE.json north-star key set: a 16-key JWKS (8×RSA-2048
+    + 8×P-256, kids ``rs-i``/``es-i``) and its signers
+    ``[(private_key, alg, kid), ...]``."""
     from .jwt.jwk import JWK
 
     jwks, signers = [], []
-    for i in range(8):
-        priv, pub = generate_keys(algs.RS256, rsa_bits=2048)
-        jwks.append(JWK(pub, kid=f"rs-{i}"))
-        signers.append((priv, algs.RS256, f"rs-{i}"))
-    for i in range(8):
-        priv, pub = generate_keys(algs.ES256)
-        jwks.append(JWK(pub, kid=f"es-{i}"))
-        signers.append((priv, algs.ES256, f"es-{i}"))
+    for alg, prefix, bits in ((algs.RS256, "rs", 2048),
+                              (algs.ES256, "es", 0)):
+        for i in range(8):
+            priv, pub = generate_keys(alg, rsa_bits=bits)
+            jwks.append(JWK(pub, kid=f"{prefix}-{i}"))
+            signers.append((priv, alg, f"{prefix}-{i}"))
+    return jwks, signers
+
+
+def headline_fixtures(n_unique: int):
+    """The north-star workload: :func:`headline_keys`' JWKS and
+    n_unique UNIQUE mixed RS256/ES256 tokens.
+
+    Shared by bench.py, tools/bench_serve.py and chip_smoke.py so the
+    offline and serving benchmarks can never desynchronize their key
+    mix.
+    """
+    jwks, signers = headline_keys()
     return jwks, sign_unique_jwts(signers, n_unique)
 
 

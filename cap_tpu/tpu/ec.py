@@ -995,41 +995,31 @@ def verify_es_packed_pending(table: ECKeyTable, rec: np.ndarray,
     Degenerate-flagged tokens (deg True) must be re-verified on the CPU
     oracle by the caller after the sync wave — same contract as
     verify_ecdsa_arrays_pending. With a mesh the record shards along
-    the batch axis; tables replicate (SURVEY.md §2.6). ``ladder``
+    the batch axis and each device verifies its own rows; tables
+    replicate (SURVEY.md §2.6). ``ladder``
     selects the window-add law (None → :func:`ladder_mode`).
     """
     ladder = resolve_ladder(ladder)
     cp = table.curve
-    if mesh is not None:
-        from ..parallel.place import replicated, shard_batch
-
-        dev = shard_batch(mesh, rec)
-        place = lambda a: replicated(mesh, a)  # noqa: E731
-    else:
-        dev = jax.device_put(rec)
-        place = lambda a: a  # noqa: E731
-
     from .rns import use_rns
 
     if use_rns():
-        from . import ec_rns
-
         rtab = table.rns()
-        consts = cp.device_consts()
-        fn = _es_packed_jit("rns", _es_packed_rns_impl,
-                            ("crv", "nbits", "wbits", "k", "cb",
-                             "hlen", "ladder"))
-        return fn(dev, place(rtab.tab),
-                  tuple(place(a) for a in consts[4:9]),
-                  crv=cp.name, nbits=cp.nbits, wbits=rtab.ctx.w_bits,
-                  k=cp.k, cb=cp.coord_bytes, hlen=hash_len,
-                  ladder=ladder)
-    fn = _es_packed_jit("limb", _es_packed_limb_impl,
-                        ("nbits", "n_windows", "k", "cb", "hlen",
-                         "pbits", "ladder"))
-    return fn(dev, place(table.tqx), place(table.tqy),
-              tuple(place(a) for a in cp.g_tables()),
-              tuple(place(a) for a in cp.device_consts()),
-              nbits=cp.nbits,
-              n_windows=cp.n_windows, k=cp.k, cb=cp.coord_bytes,
-              hlen=hash_len, pbits=cp.pbits, ladder=ladder)
+        name, impl = "rns", _es_packed_rns_impl
+        tables = (rtab.tab, tuple(cp.device_consts()[4:9]))
+        static = dict(crv=cp.name, nbits=cp.nbits, wbits=rtab.ctx.w_bits,
+                      k=cp.k, cb=cp.coord_bytes, hlen=hash_len,
+                      ladder=ladder)
+    else:
+        name, impl = "limb", _es_packed_limb_impl
+        tables = (table.tqx, table.tqy, tuple(cp.g_tables()),
+                  tuple(cp.device_consts()))
+        static = dict(nbits=cp.nbits, n_windows=cp.n_windows, k=cp.k,
+                      cb=cp.coord_bytes, hlen=hash_len, pbits=cp.pbits,
+                      ladder=ladder)
+    if mesh is not None:
+        from ..parallel.place import run_batch_sharded
+
+        return run_batch_sharded(impl, mesh, rec, tables, static)
+    fn = _es_packed_jit(name, impl, tuple(static))
+    return fn(jax.device_put(rec), *tables, **static)

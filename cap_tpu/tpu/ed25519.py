@@ -531,28 +531,22 @@ def verify_ed_packed_pending(table: Ed25519KeyTable, rec: np.ndarray,
                              mesh=None):
     """Dispatch one packed EdDSA chunk; returns the device [N] bool.
 
-    With a mesh the record shards along the batch axis; tables
-    replicate (SURVEY.md §2.6).
+    With a mesh the record shards along the batch axis and each device
+    verifies its own rows; tables replicate (SURVEY.md §2.6).
     """
     from .rns import use_rns
 
-    if mesh is not None:
-        from ..parallel.place import replicated, shard_batch
-
-        dev = shard_batch(mesh, rec)
-        place = lambda a: replicated(mesh, a)  # noqa: E731
-    else:
-        dev = jax.device_put(rec)
-        place = lambda a: a  # noqa: E731
     if use_rns():
         from . import ed25519_rns
 
-        rtab = table.rns()
-        fn = _ed_packed_jit("rns", _ed_packed_rns_impl)
-        return fn(dev, tuple(place(a) for a in rtab.tna),
-                  tuple(place(a) for a in ed25519_rns.b_table_rns()),
-                  tuple(place(a) for a in consts().dev))
-    fn = _ed_packed_jit("limb", _ed_packed_limb_impl)
-    return fn(dev, tuple(place(a) for a in table.tna),
-              tuple(place(a) for a in b_table()),
-              tuple(place(a) for a in consts().dev))
+        name, impl = "rns", _ed_packed_rns_impl
+        tables = (tuple(table.rns().tna), tuple(ed25519_rns.b_table_rns()),
+                  tuple(consts().dev))
+    else:
+        name, impl = "limb", _ed_packed_limb_impl
+        tables = (tuple(table.tna), tuple(b_table()), tuple(consts().dev))
+    if mesh is not None:
+        from ..parallel.place import run_batch_sharded
+
+        return run_batch_sharded(impl, mesh, rec, tables, {})
+    return _ed_packed_jit(name, impl)(jax.device_put(rec), *tables)

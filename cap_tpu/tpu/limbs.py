@@ -98,7 +98,7 @@ def bytes_to_limbs_device(mat):
     """Device: [N, 2K] u8 right-aligned big-endian → [K, N] u32 limbs.
 
     The host ships raw bytes (half the wire size of u32 limb arrays —
-    host↔device bandwidth is the scarce resource on tunneled setups);
+    host↔device bandwidth is a scarce resource);
     the big-endian-bytes → little-endian-limbs transform runs on
     device.
     """

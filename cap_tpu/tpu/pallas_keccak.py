@@ -318,9 +318,10 @@ def enabled() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _round_planes(planes, rc2):
+def _round_planes(planes, rc_e, rc_o):
     """One round on a [50, T] plane stack (rows 0-24 even words, rows
-    25-49 odd); ``rc2`` is the round's interleaved ι constant [1, 2].
+    25-49 odd); ``rc_e``/``rc_o`` are the round's interleaved ι
+    constant words (scalars).
     Static row slices only (the Mosaic gather rule, as in
     pallas_madd's cpA handling); shared by the kernel's round loop."""
     import jax.numpy as jnp
@@ -352,22 +353,21 @@ def _round_planes(planes, rc2):
                   & be[5 * (l // 5) + (l + 2) % 5]) for l in range(25)]
     o = [bo[l] ^ (~bo[5 * (l // 5) + (l + 1) % 5]
                   & bo[5 * (l // 5) + (l + 2) % 5]) for l in range(25)]
-    e[0] = e[0] ^ rc2[0:1, 0:1]
-    o[0] = o[0] ^ rc2[0:1, 1:2]
+    e[0] = e[0] ^ rc_e
+    o[0] = o[0] ^ rc_o
     return jnp.concatenate(e + o, axis=0)
 
 
 def _f1600_kernel(s_ref, rc_ref, o_ref):
     """The 24 rounds as an in-kernel ``fori_loop`` on a [50, T] VMEM
     tile — one compact round body instead of a 24x-unrolled graph
-    (the unrolled form compiled for minutes in interpret mode)."""
+    (the unrolled form compiled for minutes in interpret mode). The
+    round constants sit in SMEM and are read as scalars at the loop
+    index: Mosaic lowers no ``dynamic_slice`` of a value."""
     import jax
 
-    rc = rc_ref[:]                       # [24, 2] value
-
     def body(rnd, planes):
-        rc2 = jax.lax.dynamic_slice(rc, (rnd, 0), (1, 2))
-        return _round_planes(planes, rc2)
+        return _round_planes(planes, rc_ref[rnd, 0], rc_ref[rnd, 1])
 
     o_ref[:] = jax.lax.fori_loop(0, 24, body, s_ref[:])
 
@@ -385,8 +385,7 @@ def _f1600_call(planes, interpret: bool):
         grid = n // _TILE
         spec = pl.BlockSpec((50, _TILE), lambda i: (0, i),
                             memory_space=pltpu.VMEM)
-        rc_spec = pl.BlockSpec((24, 2), lambda i: (0, 0),
-                               memory_space=pltpu.VMEM)
+        rc_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
         return pl.pallas_call(
             _f1600_kernel,
             out_shape=jax.ShapeDtypeStruct((50, n), jnp.uint32),

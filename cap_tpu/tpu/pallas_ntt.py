@@ -6,9 +6,11 @@ stages — the same per-layer traffic tax the RNS REDC layers paid
 before ``pallas_madd`` (docs/PERF.md round-3: measured ~6x the pure
 read+write traffic per layer). This module runs ALL 8 stages (forward
 or inverse, including the folded 256⁻¹ scaling) on one VMEM tile per
-row block: HBM is touched once for inputs and once for outputs, the
-shape of the win the GPU Dilithium engine (PAPERS.md, arxiv
-2211.12265) demonstrates for exactly this transform.
+row block: inside the kernel HBM is touched once for inputs and once
+for outputs (plus one XLA transpose each side into the kernel's
+coefficient-major layout), the shape of the win the GPU Dilithium
+engine (PAPERS.md, arxiv 2211.12265) demonstrates for exactly this
+transform.
 
 Arithmetic is ``ntt.py``'s verbatim: uint32 Montgomery lanes, 16-bit
 limb ``_mulhi32`` REDC, no int64 anywhere (``mont_mul``/``add_q``/
@@ -18,7 +20,8 @@ drift). Twiddles ride in Montgomery form as kernel constants.
 Numerical contract: bit-identical to ``ntt.ntt``/``ntt.intt`` and the
 int64 ``ntt_ref``/``intt_ref`` host references — pinned by
 tests/test_pallas_ntt.py in interpret mode on CPU and by
-``make pallas-smoke``. Enabled via CAP_TPU_PALLAS_NTT (default ON for
+``make pallas-smoke``; tests/test_chip_compile.py compiles both
+kernels for a described v5e chip. Enabled via CAP_TPU_PALLAS_NTT (default ON for
 TPU backends; CPU keeps the XLA path — interpret mode is a
 correctness harness, and the bench_stages kernel rows publish the
 honest CPU A/B).
@@ -34,8 +37,23 @@ import numpy as np
 
 from . import ntt as _ntt
 
-_TILE_R = int(os.environ.get("CAP_TPU_NTT_TILE", 256))    # rows/step
 N = _ntt.N
+_LANES = 128                      # batch rows per vreg lane row
+_TILE_S = 8                       # sublane rows per grid step
+
+
+def _reverse_stage_segments(table) -> np.ndarray:
+    """Each inverse stage's twiddle segment [nblk, 2·nblk) reversed, so
+    the kernel slices a constant table and never reverses (Mosaic has
+    no ``rev``). Segments of different stages are disjoint."""
+    out = np.array(table, np.uint32)
+    for s in range(8):
+        nb = N >> (s + 1)
+        out[nb: 2 * nb] = out[nb: 2 * nb][::-1].copy()
+    return out
+
+
+_NEG_ZETAS_REV = _reverse_stage_segments(_ntt.NEG_ZETAS_MONT)
 
 
 def enabled() -> bool:
@@ -49,82 +67,83 @@ def enabled() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _ntt_stages(x, zetas):
-    """All 8 forward Cooley-Tukey stages on a [R, 256] VALUE (VMEM
-    array in-kernel). Butterfly-for-butterfly ntt.ntt's loop body."""
+# In-kernel layout is coefficient-major: x is [256, S, 128] with the
+# 256 coefficients on the UNTILED leading axis and the batch on the
+# (sublane, lane) tile. Every stage reshape then touches only leading
+# dims, so no shape cast ever splits the 128-lane axis (the sub-128
+# cast the chip's compiler refuses), and each butterfly is plain
+# full-vreg elementwise work. ``z`` is [256, 1, 128]: each twiddle
+# pre-broadcast along the lanes.
+
+def _ntt_stages(x, z):
+    """All 8 forward Cooley-Tukey stages; ntt.ntt's loop body."""
     import jax.numpy as jnp
 
-    r = x.shape[0]
+    tail = x.shape[1:]
     for s in range(8):
         ln = 128 >> s
         nblk = N // (2 * ln)
-        z = zetas[nblk: 2 * nblk]                    # [nblk]
-        v = x.reshape(r, nblk, 2, ln)
-        lo_, hi_ = v[:, :, 0, :], v[:, :, 1, :]
-        t = _ntt.mont_mul(z[None, :, None], hi_)
+        v = x.reshape((nblk, 2, ln) + tail)
+        lo_, hi_ = v[:, 0], v[:, 1]
+        t = _ntt.mont_mul(z[nblk: 2 * nblk][:, None], hi_)
         x = jnp.stack([_ntt.add_q(lo_, t), _ntt.sub_q(lo_, t)],
-                      axis=2).reshape(r, N)
+                      axis=1).reshape((N,) + tail)
     return x
 
 
-def _intt_stages(x, neg_zetas, inv256):
-    """All 8 Gentleman-Sande inverse stages + the folded 256⁻¹ scale
-    on a [R, 256] value; ntt.intt's loop body verbatim."""
+def _intt_stages(x, z_rev, inv256):
+    """All 8 Gentleman-Sande inverse stages + the folded 256⁻¹ scale;
+    ntt.intt's loop body, with ``z_rev`` the pre-reversed table."""
     import jax.numpy as jnp
 
-    r = x.shape[0]
+    tail = x.shape[1:]
     for s in range(8):
         ln = 1 << s
         nblk = N // (2 * ln)
-        z = neg_zetas[nblk: 2 * nblk][::-1]
-        v = x.reshape(r, nblk, 2, ln)
-        lo_, hi_ = v[:, :, 0, :], v[:, :, 1, :]
+        v = x.reshape((nblk, 2, ln) + tail)
+        lo_, hi_ = v[:, 0], v[:, 1]
         t = lo_
         lo_ = _ntt.add_q(t, hi_)
-        hi_ = _ntt.mont_mul(z[None, :, None], _ntt.sub_q(t, hi_))
-        x = jnp.stack([lo_, hi_], axis=2).reshape(r, N)
-    return _ntt.mont_mul(inv256[0, 0], x)
+        hi_ = _ntt.mont_mul(z_rev[nblk: 2 * nblk][:, None],
+                            _ntt.sub_q(t, hi_))
+        x = jnp.stack([lo_, hi_], axis=1).reshape((N,) + tail)
+    return _ntt.mont_mul(inv256, x)
 
 
 def _ntt_kernel(x_ref, z_ref, o_ref):
-    o_ref[:] = _ntt_stages(x_ref[:], z_ref[:][0])
+    o_ref[:] = _ntt_stages(x_ref[:], z_ref[:])
 
 
-def _intt_kernel(x_ref, z_ref, inv_ref, o_ref):
-    o_ref[:] = _intt_stages(x_ref[:], z_ref[:][0], inv_ref[:])
+def _intt_kernel(x_ref, z_ref, o_ref):
+    o_ref[:] = _intt_stages(x_ref[:], z_ref[:],
+                            np.uint32(_ntt.INV256_MONT))
 
 
-def _call(x2, inverse: bool, interpret: bool):
+def _call(xt, inverse: bool, interpret: bool):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     @partial(jax.jit, static_argnames=("inverse", "interpret"))
-    def run(x2, zetas, inv, inverse: bool, interpret: bool):
-        rows = x2.shape[0]
-        grid = rows // _TILE_R
-        spec = pl.BlockSpec((_TILE_R, N), lambda i: (i, 0),
+    def run(xt, z, inverse: bool, interpret: bool):
+        s_rows = xt.shape[1]
+        tile = min(_TILE_S, s_rows)
+        spec = pl.BlockSpec((N, tile, _LANES), lambda i: (0, i, 0),
                             memory_space=pltpu.VMEM)
-        z_spec = pl.BlockSpec((1, N), lambda i: (0, 0),
+        z_spec = pl.BlockSpec((N, 1, _LANES), lambda i: (0, 0, 0),
                               memory_space=pltpu.VMEM)
-        inv_spec = pl.BlockSpec((1, 1), lambda i: (0, 0),
-                                memory_space=pltpu.VMEM)
-        out = jax.ShapeDtypeStruct((rows, N), jnp.uint32)
-        if inverse:
-            return pl.pallas_call(
-                _intt_kernel, out_shape=out, grid=(grid,),
-                in_specs=[spec, z_spec, inv_spec], out_specs=spec,
-                interpret=interpret)(x2, zetas, inv)
         return pl.pallas_call(
-            _ntt_kernel, out_shape=out, grid=(grid,),
+            _intt_kernel if inverse else _ntt_kernel,
+            out_shape=jax.ShapeDtypeStruct(xt.shape, jnp.uint32),
+            grid=(s_rows // tile,),
             in_specs=[spec, z_spec], out_specs=spec,
-            interpret=interpret)(x2, zetas)
+            interpret=interpret)(xt, z)
 
-    zetas = jnp.asarray((_ntt.NEG_ZETAS_MONT if inverse
-                         else _ntt.ZETAS_MONT)[None, :])
-    inv = jnp.asarray(np.array([[_ntt.INV256_MONT]], np.uint32))
-    return run(x2, zetas, inv, inverse, interpret)
+    table = _NEG_ZETAS_REV if inverse else _ntt.ZETAS_MONT
+    z = jnp.asarray(np.broadcast_to(
+        np.asarray(table, np.uint32)[:, None, None], (N, 1, _LANES)))
+    return run(xt, z, inverse, interpret)
 
 
 def _apply(x, inverse: bool, interpret: Optional[bool]):
@@ -138,14 +157,16 @@ def _apply(x, inverse: bool, interpret: Optional[bool]):
     rows = 1
     for s in shape[:-1]:
         rows *= s
+    s_rows = -(-rows // _LANES)
+    if s_rows > _TILE_S:
+        s_rows += (-s_rows) % _TILE_S
     x2 = x.reshape(rows, N)
-    pad = (-rows) % _TILE_R
+    pad = s_rows * _LANES - rows
     if pad:
         x2 = jnp.pad(x2, ((0, pad), (0, 0)))
-    out = _call(x2, inverse, interpret)
-    if pad:
-        out = out[:rows]
-    return out.reshape(shape)
+    xt = x2.reshape(s_rows, _LANES, N).transpose(2, 0, 1)
+    out = _call(xt, inverse, interpret).transpose(1, 2, 0)
+    return out.reshape(s_rows * _LANES, N)[:rows].reshape(shape)
 
 
 def ntt_fused(x, interpret: Optional[bool] = None):

@@ -13,7 +13,12 @@ import time
 import pytest
 
 from cap_tpu import telemetry
-from cap_tpu.fleet import FleetClient, FleetExhaustedError, WorkerPool
+from cap_tpu.fleet import (
+    FleetClient,
+    FleetError,
+    FleetExhaustedError,
+    WorkerPool,
+)
 from cap_tpu.fleet.worker_main import StubKeySet, make_keyset
 from cap_tpu.parallel.place import (
     PlacementError,
@@ -38,11 +43,63 @@ def test_single_owner_placement_disjoint():
     assert env["CAP_FLEET_WORKER_ID"] == "1"
 
 
-def test_single_owner_placement_tpu_env():
-    ps = single_owner_placement(2, 4, platform="tpu")
+@pytest.mark.parametrize("n_workers,chips,bounds", [
+    (2, "0,1", "1,2,1"), (4, "0", "1,1,1")])
+def test_single_owner_placement_tpu_env(n_workers, chips, bounds):
+    """Each TPU worker is its own one-process slice over its chips,
+    on its own libtpu port."""
+    ps = single_owner_placement(n_workers, 4, platform="tpu")
     env = ps[0].env()
     assert env["JAX_PLATFORMS"] == "tpu"
-    assert env["TPU_VISIBLE_DEVICES"] == "0,1"
+    assert env["TPU_VISIBLE_CHIPS"] == chips
+    assert env["TPU_CHIPS_PER_PROCESS_BOUNDS"] == bounds
+    assert env["TPU_PROCESS_BOUNDS"] == "1,1,1"
+    ports = {p.env()["TPU_PROCESS_PORT"] for p in ps}
+    assert len(ports) == n_workers
+    assert env["TPU_PROCESS_ADDRESSES"] == (
+        "localhost:" + env["TPU_PROCESS_PORT"])
+
+
+def test_pool_requires_platform_for_device_keysets():
+    """A real engine spec never defaults silently to the CPU."""
+    for spec in ("jwks:/nonexistent.json", "jwks-url:http://x/",
+                 "oidc:https://x/"):
+        with pytest.raises(FleetError, match="explicit platform"):
+            WorkerPool(1, keyset_spec=spec)
+
+
+def test_pool_reports_and_checks_worker_devices(tmp_path):
+    """A real-engine worker names the platform and device JAX gave it;
+    one that comes up on another platform than its placement says is
+    refused, never served from."""
+    import json
+
+    from cap_tpu import testing
+    from cap_tpu.jwt.jwk import serialize_public_key
+
+    _, pub = testing.generate_keys("ES256")
+    path = tmp_path / "jwks.json"
+    path.write_text(json.dumps({"keys": [serialize_public_key(pub,
+                                                               kid="k")]}))
+    spec = f"jwks:{path}"
+    with WorkerPool(1, keyset_spec=spec, platform="cpu") as good:
+        assert good.wait_all_ready(120)
+        assert good.device_report() == {0: ("cpu", "0", None)}
+    rec = telemetry.enable()
+    try:
+        # placement says tpu, but the worker is pinned to the CPU
+        bad = WorkerPool(1, keyset_spec=spec, platform="tpu",
+                         max_restarts=0,
+                         env_extra={"JAX_PLATFORMS": "cpu",
+                                    "CAP_FLEET_CPU_DEVICES": "1"})
+        try:
+            assert not bad.wait_all_ready(120)
+            assert bad.endpoints() == {}
+            assert rec.counters().get("fleet.platform_mismatch", 0) >= 1
+        finally:
+            bad.close()
+    finally:
+        telemetry.disable()
 
 
 def test_placement_rejects_overcommit():

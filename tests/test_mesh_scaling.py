@@ -49,7 +49,8 @@ def test_packed_verdicts_shard_batch_axis(alg, n_dev):
     ks = TPUBatchKeySet([JWK(pub, kid="k0")], mesh=mesh)
     toks = [captest.sign_jwt(priv, alg, captest.default_claims(sub=f"s{i}"),
                              kid="k0") for i in range(64)] * 4
-    n_tok, fns = resident_dispatchers(ks, toks)
+    records = []
+    n_tok, fns = resident_dispatchers(ks, toks, records_out=records)
     assert n_tok == 256
 
     # The dispatcher's summed accept count must see every token once.
@@ -59,7 +60,8 @@ def test_packed_verdicts_shard_batch_axis(alg, n_dev):
     # The dispatcher's resident record itself must be placed SHARDED
     # (dev_put with a mesh) — a replication regression here would
     # still pass the accept-count check above.
-    rec0 = fns[0][1].__defaults__[0]
+    assert records
+    rec0 = records[0]
     rec_sizes = sorted(s.data.shape[0] for s in rec0.addressable_shards)
     assert len(rec_sizes) == n_dev
     assert rec_sizes == [rec0.shape[0] // n_dev] * n_dev, \

@@ -135,7 +135,7 @@ def test_hybrid_migration_es256_to_mldsa_under_load(tenant, tmp_path):
     jwks_path.write_text(json.dumps(tenant["es_jwks"]))
 
     rec = telemetry.enable()
-    pool = WorkerPool(2, keyset_spec=f"jwks:{jwks_path}",
+    pool = WorkerPool(2, keyset_spec=f"jwks:{jwks_path}", platform="cpu",
                       ping_interval=0.5, max_restarts=20,
                       spawn_timeout=120, max_wait_ms=2.0)
     try:
@@ -272,7 +272,7 @@ def test_hybrid_migration_mldsa_to_slhdsa_under_load(tenant, tmp_path):
     jwks_path.write_text(json.dumps(tenant["hybrid_jwks"]))
 
     rec = telemetry.enable()
-    pool = WorkerPool(2, keyset_spec=f"jwks:{jwks_path}",
+    pool = WorkerPool(2, keyset_spec=f"jwks:{jwks_path}", platform="cpu",
                       ping_interval=0.5, max_restarts=20,
                       spawn_timeout=120, max_wait_ms=2.0)
     try:

@@ -71,7 +71,7 @@ def test_valid_tokens_match_python():
         assert res.signature == ref.signature
         assert res.payload == ref.payload
         assert res.signing_input == ref.signing_input
-        if ref.alg != "EdDSA":
+        if ref.alg != "EdDSA" and ref.alg in algs.HASH_FOR_ALG:
             hname = algs.HASH_FOR_ALG[ref.alg]
             assert res.digest() == hashlib.new(
                 hname, ref.signing_input).digest()

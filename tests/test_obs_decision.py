@@ -451,9 +451,13 @@ def test_obs_server_stalled_scraper_does_not_block(tmp_path):
 # bench-trend sentinel
 # ---------------------------------------------------------------------------
 
+SERIES_FIXTURE = os.path.join(REPO, "tests", "data", "bench_series")
+
+
 def test_bench_trend_selftest_and_real_series():
     assert bench_trend.selftest(REPO) == []
-    series = bench_trend.load_series(REPO)
+    assert bench_trend.selftest(SERIES_FIXTURE) == []
+    series = bench_trend.load_series(SERIES_FIXTURE)
     assert len(series) >= 5
     assert bench_trend.check_series(series) == [], \
         "committed BENCH series must pass clean"

@@ -8,7 +8,7 @@ planes, 2N lanes):
   core   — the full _ecdsa_rns_core for reference
 
 All chains use the slope method ((t(1+R) - t(1)) / R) so dispatch and
-sync constants cancel (tunnel methodology, docs/PERF.md).
+sync constants cancel (slope methodology, docs/PERF.md).
 """
 
 import os
