@@ -21,6 +21,8 @@ Pipeline per batch:
 from __future__ import annotations
 
 import os
+import re
+import threading
 import time
 from typing import Any, Dict, List, Optional, Sequence
 
@@ -94,6 +96,87 @@ def _pad_telemetry(family: str, m: int, pad: int) -> None:
     telemetry.gauge(f"device.{family}.last_lanes", pad)
 
 
+def _sig_arrays(pb, chunk: np.ndarray, crows: np.ndarray, width: int,
+                pad: int):
+    """``chunk``'s signatures, lengths, digests and key rows as
+    zero-padded arrays of ``pad`` rows: the array-path engines' inputs,
+    and the numpy packers' (pre-packer .so)."""
+    m = len(chunk)
+    sig_mat = np.zeros((pad, width), np.uint8)
+    sig_mat[:m] = pb.sig_matrix(chunk, width)
+    sig_lens = np.zeros(pad, np.int64)
+    sig_lens[:m] = pb.sig_len[chunk]
+    hash_mat = np.zeros((pad, 64), np.uint8)
+    hash_mat[:m] = pb.digest[chunk]
+    key_idx = np.zeros(pad, np.int32)
+    key_idx[:m] = crows
+    return sig_mat, sig_lens, hash_mat, key_idx
+
+
+def _route(pb, idx: np.ndarray, kid_to_row: dict, single_key: bool,
+           slow: List[int]):
+    """Key-table rows for the tokens ``idx``: returns the ``(idx,
+    rows)`` that go to the device, and appends the rest (a kid no row
+    matches) to ``slow``. A kid-less token has exactly one candidate in
+    a single-key table, so it goes to row 0 — the object path's
+    single-candidate routing."""
+    with telemetry.span(telemetry.SPAN_KEYSET_ROUTE):
+        rows = pb.kid_rows(idx, kid_to_row)
+    if single_key:
+        rows = np.where(rows == -1, 0, rows)
+    fast = rows >= 0
+    slow.extend(int(i) for i in idx[~fast])
+    return idx[fast], rows[fast].astype(np.int32)
+
+
+# JAX's duration events for one program traced / built (or loaded from
+# the persistent cache); both carry the function's name as fun_name.
+_JAXPR_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_jax_hooks_lock = threading.Lock()
+_jax_hooks_installed = False
+
+
+def _program_label(fun_name: str) -> str:
+    """A counter-name-safe label for one program: ``jit(_f)`` reads
+    ``jit__f``. Names are JAX's function names, a bounded set."""
+    label = re.sub(r"[^A-Za-z0-9_.-]+", "_", fun_name).strip("_")[:80]
+    return label.replace("eyJ", "ey_J") or "unnamed"
+
+
+def _on_jax_duration(event: str, duration: float, **kw: Any) -> None:
+    """``jax.monitoring`` listener: every program built or loaded from
+    the persistent cache counts into ``compile.programs`` and
+    ``compile.fn.<name>``, its seconds into ``compile.backend_s``;
+    every trace into ``compile.traces``."""
+    rec = telemetry.active()
+    if rec is None:
+        return
+    if event == _BACKEND_COMPILE_EVENT:
+        label = _program_label(str(kw.get("fun_name", "")))
+        rec.count_many({"compile.programs": 1, f"compile.fn.{label}": 1})
+        rec.observe("compile.backend_s", duration)
+    elif event == _JAXPR_TRACE_EVENT:
+        rec.count("compile.traces")
+
+
+def install_jax_hooks() -> None:
+    """Once per process: every telemetry span becomes a profiler host
+    event while a profile captures, and the compile counter listens to
+    JAX. :class:`TPUBatchKeySet` calls this; telemetry itself never
+    imports JAX."""
+    global _jax_hooks_installed
+    with _jax_hooks_lock:
+        if _jax_hooks_installed:
+            return
+        import jax
+
+        telemetry.install_profiler(jax.profiler.TraceAnnotation)
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_jax_duration)
+        _jax_hooks_installed = True
+
+
 def _pack_rsa_record(pb, table, kind: str, hash_name: str,
                      chunk: np.ndarray, crows: np.ndarray,
                      pad: int) -> np.ndarray:
@@ -117,14 +200,8 @@ def _pack_rsa_record(pb, table, kind: str, hash_name: str,
     rec = pb.pack_sig_records(chunk, sizes, extra, crows, width,
                               h_len, pad)
     if rec is None:               # pre-packer .so: numpy path
-        sig_mat = np.zeros((pad, width), np.uint8)
-        sig_mat[:m] = pb.sig_matrix(chunk, width)
-        sig_lens = np.zeros(pad, np.int64)
-        sig_lens[:m] = pb.sig_len[chunk]
-        hash_mat = np.zeros((pad, 64), np.uint8)
-        hash_mat[:m] = pb.digest[chunk]
-        key_idx = np.zeros(pad, np.int32)
-        key_idx[:m] = crows
+        sig_mat, sig_lens, hash_mat, key_idx = _sig_arrays(
+            pb, chunk, crows, width, pad)
         rec = tpursa.rs_packed_records(table, sig_mat, sig_lens,
                                        hash_mat, hash_name, key_idx)
         if kind == "ps":
@@ -150,14 +227,8 @@ def _pack_es_record(pb, table, chunk: np.ndarray, crows: np.ndarray,
                               np.ones(m, np.uint8), crows, width,
                               hash_len, pad)
     if rec is None:               # pre-packer .so: numpy path
-        sig_mat = np.zeros((pad, width), np.uint8)
-        sig_mat[:m] = pb.sig_matrix(chunk, width)
-        sig_lens = np.zeros(pad, np.int64)
-        sig_lens[:m] = pb.sig_len[chunk]
-        hash_mat = np.zeros((pad, 64), np.uint8)
-        hash_mat[:m] = pb.digest[chunk]
-        key_idx = np.zeros(pad, np.int32)
-        key_idx[:m] = crows
+        sig_mat, sig_lens, hash_mat, key_idx = _sig_arrays(
+            pb, chunk, crows, width, pad)
         rec = tpuec.es_packed_records(table, sig_mat, sig_lens,
                                       hash_mat, hash_len, key_idx)
     return rec
@@ -743,6 +814,7 @@ class TPUBatchKeySet(KeySet):
                  ec_ladder: Optional[str] = None, epoch: int = 0):
         if not jwks:
             raise NilParameterError("at least one key is required")
+        install_jax_hooks()
         if ec_ladder is not None:
             from ..tpu.ec import resolve_ladder
 
@@ -761,8 +833,6 @@ class TPUBatchKeySet(KeySet):
         self._last_collect_t: Optional[float] = None
         self._chunk_budget_s = float(os.environ.get(
             "CAP_TPU_CHUNK_BUDGET_MS", "250")) / 1e3
-        import threading
-
         self._swap_lock = threading.Lock()
         self._tables = _KeyTables(jwks, epoch=epoch)
 
@@ -1059,14 +1129,16 @@ class TPUBatchKeySet(KeySet):
         occ_t0 = _occupancy.begin()
         from .jose import normalize_batch
 
-        tokens, specials = normalize_batch(tokens)
+        with telemetry.span(telemetry.SPAN_KEYSET_NORMALIZE):
+            tokens, specials = normalize_batch(tokens)
         with telemetry.span("prep.native"):
             pb = prepare_batch_arrays(tokens)
         n = pb.n
         results: List[Any] = [None] * n
-        ok = pb.status == 0
-        for i in np.nonzero(~ok)[0]:
-            results[int(i)] = pb.error(int(i))
+        with telemetry.span(telemetry.SPAN_KEYSET_ROUTE):
+            ok = pb.status == 0
+            for i in np.nonzero(~ok)[0]:
+                results[int(i)] = pb.error(int(i))
         special_payloads: Dict[int, bytes] = {}
         for i, sp in specials.items():
             # normalization verdicts outrank the ""-sentinel's prep
@@ -1098,7 +1170,8 @@ class TPUBatchKeySet(KeySet):
         alg_ids = {name: i for i, name in enumerate(ALG_NAMES)}
 
         def run_family(alg_name: str, runner) -> None:
-            idx = np.nonzero(ok & (pb.alg_id == alg_ids[alg_name]))[0]
+            with telemetry.span(telemetry.SPAN_KEYSET_ROUTE):
+                idx = np.nonzero(ok & (pb.alg_id == alg_ids[alg_name]))[0]
             if len(idx) == 0:
                 return
             runner(alg_name, idx)
@@ -1174,30 +1247,32 @@ class TPUBatchKeySet(KeySet):
         packed_meta = state["packed_meta"]
 
         raw = state.get("raw", False)
-        with telemetry.span("device.sync"):
-            if raw:
-                # Raw mode replaces dict building with the phase-1-only
-                # object check; the mask drives _finish_arrays for the
-                # packed AND arrays paths, overlapping the drain.
-                with telemetry.span("claims.validate"):
-                    idxs = np.nonzero(ok)[0]
-                    mask = np.zeros(n, bool)
-                    mask[idxs] = pb.payload_object_ok(idxs)
-                    pb._raw_ok = mask
-            if packed_parts:
-                import jax.numpy as jnp
+        if raw:
+            # Raw mode replaces dict building with the phase-1-only
+            # object check; the mask drives _finish_arrays for the
+            # packed AND arrays paths, overlapping the drain.
+            with telemetry.span("claims.validate"):
+                idxs = np.nonzero(ok)[0]
+                mask = np.zeros(n, bool)
+                mask[idxs] = pb.payload_object_ok(idxs)
+                pb._raw_ok = mask
+        if packed_parts:
+            import jax.numpy as jnp
 
+            with telemetry.span(telemetry.SPAN_KEYSET_LAUNCH):
                 flat_dev = (jnp.concatenate(packed_parts)
                             if len(packed_parts) > 1 else packed_parts[0])
-                # Overlap the host-side claims parsing with the device
-                # drain (transfers + compute are still in flight; only
-                # np.asarray below truly blocks). Every ok-status token
-                # still has results[i] None here (only prep errors are
-                # filled), so the index set is just the ok mask.
-                if not raw:
-                    with telemetry.span("claims.prefetch"):
-                        pb.prefetch_claims(np.nonzero(ok)[0])
+            # Overlap the host-side claims parsing with the device
+            # drain (transfers + compute are still in flight; only
+            # np.asarray below truly blocks). Every ok-status token
+            # still has results[i] None here (only prep errors are
+            # filled), so the index set is just the ok mask.
+            if not raw:
+                with telemetry.span("claims.prefetch"):
+                    pb.prefetch_claims(np.nonzero(ok)[0])
+            with telemetry.span(telemetry.SPAN_KEYSET_WAIT):
                 flat = np.asarray(flat_dev)
+            with telemetry.span(telemetry.SPAN_KEYSET_FINISH):
                 off = 0
                 for n_slots, consume in packed_meta:
                     arrs = []
@@ -1205,14 +1280,18 @@ class TPUBatchKeySet(KeySet):
                         arrs.append(flat[off:off + sz])
                         off += sz
                     consume(arrs)
-            for chunk, m, fin in pending:
-                self._finish_arrays(chunk, fin()[:m], pb, results)
+        for chunk, m, fin in pending:
+            with telemetry.span(telemetry.SPAN_KEYSET_WAIT):
+                okv = fin()[:m]
+            with telemetry.span(telemetry.SPAN_KEYSET_FINISH):
+                self._finish_arrays(chunk, okv, pb, results)
 
         # families without device tables (or EC/Ed engines not built):
-        slow_set = set(slow)
-        for j in range(n):
-            if ok[j] and results[j] is None and j not in slow_set:
-                slow_set.add(j)
+        with telemetry.span(telemetry.SPAN_KEYSET_FINISH):
+            slow_set = set(slow)
+            for j in range(n):
+                if ok[j] and results[j] is None and j not in slow_set:
+                    slow_set.add(j)
 
         if slow_set:
             telemetry.count("cpu_fallback.tokens", len(slow_set))
@@ -1248,12 +1327,13 @@ class TPUBatchKeySet(KeySet):
                 # non-interned algs (ML-DSA et al.) carry raw bytes
                 return _decision.family_for_alg(pb.alg(j))
 
-            fams = [fam(j) for j in range(n)]
-            t_dispatch = state.get("t_dispatch")
-            _decision.record_batch(
-                "tpu", results, families=fams,
-                latency_s=(time.perf_counter() - t_dispatch
-                           if t_dispatch is not None else None))
+            with telemetry.span(telemetry.SPAN_KEYSET_DECISIONS):
+                fams = [fam(j) for j in range(n)]
+                t_dispatch = state.get("t_dispatch")
+                _decision.record_batch(
+                    "tpu", results, families=fams,
+                    latency_s=(time.perf_counter() - t_dispatch
+                               if t_dispatch is not None else None))
         # Close the batch's dispatch-level busy interval: dispatch
         # start → collect end is the window this batch held device
         # work in flight (the occupancy numerator).
@@ -1288,7 +1368,6 @@ class TPUBatchKeySet(KeySet):
             return
         prev = self._wire_bps
         self._wire_bps = est if prev is None else 0.5 * prev + 0.5 * est
-        telemetry.observe("wire.est_mbps", self._wire_bps / (1 << 20))
 
     @staticmethod
     def _finish_arrays(chunk, okv, pb, results: List[Any]) -> None:
@@ -1364,22 +1443,17 @@ class TPUBatchKeySet(KeySet):
         from ..tpu import rsa as tpursa
 
         t = self._tables if tables is None else tables
-        rows = pb.kid_rows(idx, t.kid_rsa_row)
-        if t.n_rsa_keys == 1:
-            rows = np.where(rows == -1, 0, rows)
-        fast = rows >= 0
-        slow.extend(int(i) for i in idx[~fast])
-        idx = idx[fast]
-        rows = rows[fast].astype(np.int32)
+        idx, rows = _route(pb, idx, t.kid_rsa_row, t.n_rsa_keys == 1, slow)
         if len(idx) == 0:
             return
         h_len = tpursa.HASH_LEN[hash_name]
         for cls, table in enumerate(t.rsa_tables):
-            sel = (rows // _RSA_CLS_STRIDE) == cls
-            if not sel.any():
+            with telemetry.span(telemetry.SPAN_KEYSET_ROUTE):
+                sel = (rows // _RSA_CLS_STRIDE) == cls
+                cls_idx = idx[sel]
+                cls_rows = rows[sel] % _RSA_CLS_STRIDE
+            if len(cls_idx) == 0:
                 continue
-            cls_idx = idx[sel]
-            cls_rows = rows[sel] % _RSA_CLS_STRIDE
             if len(table.n_ints) > 255:    # kid row must fit a u8
                 self._run_rsa_arrays(kind, hash_name, cls_idx, pb,
                                      pending, slow, stats, cls=cls,
@@ -1396,16 +1470,16 @@ class TPUBatchKeySet(KeySet):
                 telemetry.count(f"device.{kind}.tokens", m)
                 _pad_telemetry(kind, m, pad)
                 with telemetry.span(f"dispatch.{kind}.{hash_name}"):
-                    rec = _pack_rsa_record(pb, table, kind, hash_name,
-                                           chunk, crows, pad)
+                    with telemetry.span(telemetry.SPAN_KEYSET_PACK):
+                        rec = _pack_rsa_record(pb, table, kind, hash_name,
+                                               chunk, crows, pad)
                     telemetry.count("h2d.bytes", rec.nbytes)
                     stats["h2d"] += rec.nbytes
-                    if kind == "rs":
-                        ok_dev = tpursa.verify_rs_packed_pending(
-                            table, rec, hash_name, mesh=self._mesh)
-                    else:
-                        ok_dev = tpursa.verify_ps_packed_pending(
-                            table, rec, hash_name, mesh=self._mesh)
+                    verify = (tpursa.verify_rs_packed_pending if kind == "rs"
+                              else tpursa.verify_ps_packed_pending)
+                    with telemetry.span(telemetry.SPAN_KEYSET_LAUNCH):
+                        ok_dev = verify(table, rec, hash_name,
+                                        mesh=self._mesh)
                 packed_parts.append(ok_dev)
 
                 def consume(arrs, chunk=chunk, m=m):
@@ -1430,13 +1504,8 @@ class TPUBatchKeySet(KeySet):
             return self._run_ec_arrays(alg, idx, pb, pending, slow,
                                        stats, tables=t)
         hash_len = HASH_LEN[algs.HASH_FOR_ALG[alg]]
-        rows = pb.kid_rows(idx, t.kid_ec_row[crv])
-        if len(table.keys) == 1:
-            rows = np.where(rows == -1, 0, rows)
-        fast = rows >= 0
-        slow.extend(int(i) for i in idx[~fast])
-        idx = idx[fast]
-        rows = rows[fast].astype(np.int32)
+        idx, rows = _route(pb, idx, t.kid_ec_row[crv],
+                           len(table.keys) == 1, slow)
         if len(idx) == 0:
             return
         cb = table.curve.coord_bytes
@@ -1450,13 +1519,15 @@ class TPUBatchKeySet(KeySet):
             telemetry.count("device.es.tokens", m)
             _pad_telemetry("es", m, pad)
             with telemetry.span(f"dispatch.es.{crv}"):
-                rec = _pack_es_record(pb, table, chunk, crows,
-                                      hash_len, pad)
+                with telemetry.span(telemetry.SPAN_KEYSET_PACK):
+                    rec = _pack_es_record(pb, table, chunk, crows,
+                                          hash_len, pad)
                 telemetry.count("h2d.bytes", rec.nbytes)
                 stats["h2d"] += rec.nbytes
-                ok_dev, deg_dev = tpuec.verify_es_packed_pending(
-                    table, rec, hash_len, mesh=self._mesh,
-                    ladder=self._ec_ladder)
+                with telemetry.span(telemetry.SPAN_KEYSET_LAUNCH):
+                    ok_dev, deg_dev = tpuec.verify_es_packed_pending(
+                        table, rec, hash_len, mesh=self._mesh,
+                        ladder=self._ec_ladder)
             packed_parts.append(ok_dev)
             packed_parts.append(deg_dev)
 
@@ -1496,13 +1567,8 @@ class TPUBatchKeySet(KeySet):
         t = self._tables if tables is None else tables
         table = t.mldsa_tables[pset]
         p = table.params
-        rows = pb.kid_rows(idx, t.kid_mldsa_row[pset])
-        if len(table.keys) == 1:
-            rows = np.where(rows == -1, 0, rows)
-        fast = rows >= 0
-        slow.extend(int(i) for i in idx[~fast])
-        idx = idx[fast]
-        rows = rows[fast].astype(np.int32)
+        idx, rows = _route(pb, idx, t.kid_mldsa_row[pset],
+                           len(table.keys) == 1, slow)
         if len(idx) == 0:
             return
         # Per-token device bytes: z lanes (l·256 u32) + c lanes
@@ -1514,19 +1580,22 @@ class TPUBatchKeySet(KeySet):
             crows = rows[lo: lo + chunk_n]
             m = len(chunk)
             pad = _pad_size(m, chunk_n)
-            sigs = [pb.signature(int(j)) for j in chunk]
-            msgs = [pb.signing_input(int(j)) for j in chunk]
             telemetry.count("device.mldsa.tokens", m)
             _pad_telemetry("mldsa", m, pad)
             h2d = pad * bpt
             telemetry.count("h2d.bytes", h2d)
             stats["h2d"] += h2d
             with telemetry.span(f"dispatch.mldsa.{pset}"):
+                with telemetry.span(telemetry.SPAN_KEYSET_PACK):
+                    sigs = [pb.signature(int(j)) for j in chunk]
+                    msgs = [pb.signing_input(int(j)) for j in chunk]
                 verify = (tpumldsa.verify_mldsa_fused_pending
                           if tpumldsa.fused_enabled()
                           else tpumldsa.verify_mldsa_pending)
-                fin = verify(table, sigs, msgs, crows, pad=pad,
-                             mesh=self._mesh)
+                # the engine's own byte decode runs inside the launch
+                with telemetry.span(telemetry.SPAN_KEYSET_LAUNCH):
+                    fin = verify(table, sigs, msgs, crows, pad=pad,
+                                 mesh=self._mesh)
             pending.append((chunk, m, fin))
 
     def _run_slhdsa_packed(self, pset: str, idx: np.ndarray, pb,
@@ -1544,13 +1613,8 @@ class TPUBatchKeySet(KeySet):
         t = self._tables if tables is None else tables
         table = t.slhdsa_tables[pset]
         p = table.params
-        rows = pb.kid_rows(idx, t.kid_slhdsa_row[pset])
-        if len(table.keys) == 1:
-            rows = np.where(rows == -1, 0, rows)
-        fast = rows >= 0
-        slow.extend(int(i) for i in idx[~fast])
-        idx = idx[fast]
-        rows = rows[fast].astype(np.int32)
+        idx, rows = _route(pb, idx, t.kid_slhdsa_row[pset],
+                           len(table.keys) == 1, slow)
         if len(idx) == 0:
             return
         # Per-token device bytes ≈ the signature's hash values plus
@@ -1571,16 +1635,20 @@ class TPUBatchKeySet(KeySet):
             while pad < m:
                 pad *= 2
             pad = min(pad, chunk_n)
-            sigs = [pb.signature(int(j)) for j in chunk]
-            msgs = [pb.signing_input(int(j)) for j in chunk]
             telemetry.count("device.slhdsa.tokens", m)
             _pad_telemetry("slhdsa", m, pad)
             h2d = pad * bpt
             telemetry.count("h2d.bytes", h2d)
             stats["h2d"] += h2d
             with telemetry.span(f"dispatch.slhdsa.{pset}"):
-                fin = tpuslh.verify_slhdsa_pending(
-                    table, sigs, msgs, crows, pad=pad, mesh=self._mesh)
+                with telemetry.span(telemetry.SPAN_KEYSET_PACK):
+                    sigs = [pb.signature(int(j)) for j in chunk]
+                    msgs = [pb.signing_input(int(j)) for j in chunk]
+                # the engine's own host decode runs inside the launch
+                with telemetry.span(telemetry.SPAN_KEYSET_LAUNCH):
+                    fin = tpuslh.verify_slhdsa_pending(
+                        table, sigs, msgs, crows, pad=pad,
+                        mesh=self._mesh)
             pending.append((chunk, m, fin))
 
     def _run_rsa_arrays(self, kind: str, hash_name: str, idx: np.ndarray,
@@ -1591,16 +1659,7 @@ class TPUBatchKeySet(KeySet):
         from ..tpu import rsa as tpursa
 
         t = self._tables if tables is None else tables
-        rows = pb.kid_rows(idx, t.kid_rsa_row)
-        if t.n_rsa_keys == 1:
-            # single-key family: kid-less tokens have exactly one
-            # candidate — dispatch them to the device (row 0), matching
-            # the object path's single-candidate routing
-            rows = np.where(rows == -1, 0, rows)
-        fast = rows >= 0
-        slow.extend(int(i) for i in idx[~fast])
-        idx = idx[fast]
-        rows = rows[fast].astype(np.int32)
+        idx, rows = _route(pb, idx, t.kid_rsa_row, t.n_rsa_keys == 1, slow)
         if len(idx) == 0:
             return
         for c, table in enumerate(t.rsa_tables):
@@ -1617,29 +1676,21 @@ class TPUBatchKeySet(KeySet):
                 crows = cls_rows[lo: lo + self._max_chunk]
                 m = len(chunk)
                 pad = _pad_size(m, self._max_chunk)
-                sig_mat = np.zeros((pad, width), np.uint8)
-                sig_mat[:m] = pb.sig_matrix(chunk, width)
-                sig_lens = np.zeros(pad, np.int64)
-                sig_lens[:m] = pb.sig_len[chunk]
-                hash_mat = np.zeros((pad, 64), np.uint8)
-                hash_mat[:m] = pb.digest[chunk]
-                key_idx = np.zeros(pad, np.int32)
-                key_idx[:m] = crows
                 telemetry.count(f"device.{kind}.tokens", m)
                 _pad_telemetry(kind, m, pad)
-                h2d = (sig_mat.nbytes + sig_lens.nbytes
-                       + hash_mat.nbytes + key_idx.nbytes)
-                telemetry.count("h2d.bytes", h2d)
-                stats["h2d"] += h2d
                 with telemetry.span(f"dispatch.{kind}.{hash_name}"):
-                    if kind == "rs":
-                        fin = tpursa.verify_pkcs1v15_arrays_pending(
-                            table, sig_mat, sig_lens, hash_mat,
-                            hash_name, key_idx)
-                    else:
-                        fin = tpursa.verify_pss_arrays_pending(
-                            table, sig_mat, sig_lens, hash_mat,
-                            hash_name, key_idx)
+                    with telemetry.span(telemetry.SPAN_KEYSET_PACK):
+                        arrays = _sig_arrays(pb, chunk, crows, width, pad)
+                    h2d = sum(a.nbytes for a in arrays)
+                    telemetry.count("h2d.bytes", h2d)
+                    stats["h2d"] += h2d
+                    verify = (tpursa.verify_pkcs1v15_arrays_pending
+                              if kind == "rs"
+                              else tpursa.verify_pss_arrays_pending)
+                    sig_mat, sig_lens, hash_mat, key_idx = arrays
+                    with telemetry.span(telemetry.SPAN_KEYSET_LAUNCH):
+                        fin = verify(table, sig_mat, sig_lens, hash_mat,
+                                     hash_name, key_idx)
                 pending.append((chunk, m, fin))
 
     def _run_ec_arrays(self, alg: str, idx: np.ndarray, pb,
@@ -1653,14 +1704,8 @@ class TPUBatchKeySet(KeySet):
         crv = _ES[alg]
         table = t.ec_tables[crv]
         hash_len = HASH_LEN[algs.HASH_FOR_ALG[alg]]
-        rows = pb.kid_rows(idx, t.kid_ec_row[crv])
-        if len(table.keys) == 1:
-            # kid-less tokens have exactly one candidate on this curve
-            rows = np.where(rows == -1, 0, rows)
-        fast = rows >= 0
-        slow.extend(int(i) for i in idx[~fast])
-        idx = idx[fast]
-        rows = rows[fast].astype(np.int32)
+        idx, rows = _route(pb, idx, t.kid_ec_row[crv],
+                           len(table.keys) == 1, slow)
         if len(idx) == 0:
             return
         width = 2 * table.coord_bytes
@@ -1669,24 +1714,19 @@ class TPUBatchKeySet(KeySet):
             crows = rows[lo: lo + self._max_chunk]
             m = len(chunk)
             pad = _pad_size(m, self._max_chunk)
-            sig_mat = np.zeros((pad, width), np.uint8)
-            sig_mat[:m] = pb.sig_matrix(chunk, width)
-            sig_lens = np.zeros(pad, np.int64)
-            sig_lens[:m] = pb.sig_len[chunk]
-            hash_mat = np.zeros((pad, 64), np.uint8)
-            hash_mat[:m] = pb.digest[chunk]
-            key_idx = np.zeros(pad, np.int32)
-            key_idx[:m] = crows
             telemetry.count("device.es.tokens", m)
             _pad_telemetry("es", m, pad)
-            h2d = (sig_mat.nbytes + sig_lens.nbytes + hash_mat.nbytes
-                   + key_idx.nbytes)
-            telemetry.count("h2d.bytes", h2d)
-            stats["h2d"] += h2d
             with telemetry.span(f"dispatch.es.{crv}"):
-                fin = tpuec.verify_ecdsa_arrays_pending(
-                    table, sig_mat, sig_lens, hash_mat, hash_len,
-                    key_idx, ladder=self._ec_ladder)
+                with telemetry.span(telemetry.SPAN_KEYSET_PACK):
+                    arrays = _sig_arrays(pb, chunk, crows, width, pad)
+                h2d = sum(a.nbytes for a in arrays)
+                telemetry.count("h2d.bytes", h2d)
+                stats["h2d"] += h2d
+                sig_mat, sig_lens, hash_mat, key_idx = arrays
+                with telemetry.span(telemetry.SPAN_KEYSET_LAUNCH):
+                    fin = tpuec.verify_ecdsa_arrays_pending(
+                        table, sig_mat, sig_lens, hash_mat, hash_len,
+                        key_idx, ladder=self._ec_ladder)
             pending.append((chunk, m, fin))
 
     def _run_ed_packed(self, idx: np.ndarray, pb,
@@ -1703,13 +1743,7 @@ class TPUBatchKeySet(KeySet):
         if len(table.keys) > 255:
             return self._run_ed_arrays(idx, pb, pending, slow, stats,
                                        tables=t)
-        rows = pb.kid_rows(idx, t.kid_ed_row)
-        if len(table.keys) == 1:
-            rows = np.where(rows == -1, 0, rows)
-        fast = rows >= 0
-        slow.extend(int(i) for i in idx[~fast])
-        idx = idx[fast]
-        rows = rows[fast].astype(np.int32)
+        idx, rows = _route(pb, idx, t.kid_ed_row, len(table.keys) == 1, slow)
         if len(idx) == 0:
             return
         chunk_n = self._chunk_tokens(64 + 32 + tpued.ED_REC_EXTRA)
@@ -1718,20 +1752,24 @@ class TPUBatchKeySet(KeySet):
             crows = rows[lo: lo + chunk_n]
             m = len(chunk)
             pad = _pad_size(m, chunk_n)
-            sigs = [pb.signature(int(j)) for j in chunk]
-            msgs = [pb.signing_input(int(j)) for j in chunk]
-            fill = pad - m
-            sigs += [b""] * fill
-            msgs += [b""] * fill
-            key_idx = np.concatenate([crows, np.zeros(fill, np.int32)])
             telemetry.count("device.ed.tokens", m)
             _pad_telemetry("ed", m, pad)
             with telemetry.span("dispatch.ed25519"):
-                rec = tpued.ed_packed_records(table, sigs, msgs, key_idx)
+                with telemetry.span(telemetry.SPAN_KEYSET_PACK):
+                    fill = pad - m
+                    sigs = [pb.signature(int(j)) for j in chunk]
+                    msgs = [pb.signing_input(int(j)) for j in chunk]
+                    sigs += [b""] * fill
+                    msgs += [b""] * fill
+                    key_idx = np.concatenate(
+                        [crows, np.zeros(fill, np.int32)])
+                    rec = tpued.ed_packed_records(table, sigs, msgs,
+                                                  key_idx)
                 telemetry.count("h2d.bytes", rec.nbytes)
                 stats["h2d"] += rec.nbytes
-                ok_dev = tpued.verify_ed_packed_pending(
-                    table, rec, mesh=self._mesh)
+                with telemetry.span(telemetry.SPAN_KEYSET_LAUNCH):
+                    ok_dev = tpued.verify_ed_packed_pending(
+                        table, rec, mesh=self._mesh)
             packed_parts.append(ok_dev)
 
             def consume(arrs, chunk=chunk, m=m):
@@ -1747,14 +1785,7 @@ class TPUBatchKeySet(KeySet):
 
         t = self._tables if tables is None else tables
         table = t.ed_table
-        rows = pb.kid_rows(idx, t.kid_ed_row)
-        if len(table.keys) == 1:
-            # kid-less tokens have exactly one EdDSA candidate
-            rows = np.where(rows == -1, 0, rows)
-        fast = rows >= 0
-        slow.extend(int(i) for i in idx[~fast])
-        idx = idx[fast]
-        rows = rows[fast].astype(np.int32)
+        idx, rows = _route(pb, idx, t.kid_ed_row, len(table.keys) == 1, slow)
         if len(idx) == 0:
             return
         for lo in range(0, len(idx), self._max_chunk):
@@ -1762,21 +1793,24 @@ class TPUBatchKeySet(KeySet):
             crows = rows[lo: lo + self._max_chunk]
             m = len(chunk)
             pad = _pad_size(m, self._max_chunk)
-            sigs = [pb.signature(int(j)) for j in chunk]
-            msgs = [pb.signing_input(int(j)) for j in chunk]
-            fill = pad - m
-            sigs += [b"\x00" * 64] * fill
-            msgs += [b""] * fill
-            key_idx = np.concatenate([crows, np.zeros(fill, np.int32)])
             telemetry.count("device.ed.tokens", m)
             _pad_telemetry("ed", m, pad)
-            h2d = (sum(len(x) for x in sigs)
-                   + sum(len(x) for x in msgs) + key_idx.nbytes)
-            telemetry.count("h2d.bytes", h2d)
-            stats["h2d"] += h2d
             with telemetry.span("dispatch.ed25519"):
-                fin = tpued.verify_ed25519_batch_pending(
-                    table, sigs, msgs, key_idx)
+                with telemetry.span(telemetry.SPAN_KEYSET_PACK):
+                    fill = pad - m
+                    sigs = [pb.signature(int(j)) for j in chunk]
+                    msgs = [pb.signing_input(int(j)) for j in chunk]
+                    sigs += [b"\x00" * 64] * fill
+                    msgs += [b""] * fill
+                    key_idx = np.concatenate(
+                        [crows, np.zeros(fill, np.int32)])
+                h2d = (sum(len(x) for x in sigs)
+                       + sum(len(x) for x in msgs) + key_idx.nbytes)
+                telemetry.count("h2d.bytes", h2d)
+                stats["h2d"] += h2d
+                with telemetry.span(telemetry.SPAN_KEYSET_LAUNCH):
+                    fin = tpued.verify_ed25519_batch_pending(
+                        table, sigs, msgs, key_idx)
             pending.append((chunk, m, fin))
 
     def _verify_one_parsed(self, p,

@@ -466,7 +466,8 @@ class AdaptiveBatcher:
             lf["dispatch_gap_s"] = t_dispatch - t_flush
             try:
                 with telemetry.trace_scope(traces), \
-                        telemetry.span(telemetry.SPAN_BATCHER_DISPATCH):
+                        telemetry.span(telemetry.SPAN_BATCHER_DISPATCH,
+                                       cpu=True):
                     collect = dispatch(send_tokens)
             except Exception as e:  # noqa: BLE001 - fan the failure out
                 self._slot.release()
@@ -521,7 +522,8 @@ class AdaptiveBatcher:
                                   or ((p.trace,) if p.trace else ()))]
             try:
                 with telemetry.trace_scope(traces), \
-                        telemetry.span(telemetry.SPAN_BATCHER_COLLECT):
+                        telemetry.span(telemetry.SPAN_BATCHER_COLLECT,
+                                       cpu=True):
                     results = self._expand(collect(), expand)
             except Exception as e:  # noqa: BLE001 - fan the failure out
                 results = [e] * n_tokens
@@ -555,6 +557,7 @@ class AdaptiveBatcher:
                 # native chain records its traced requests' flight
                 # entries itself (it knows each request's t0).
                 try:
-                    p.on_done(p.results)
+                    with telemetry.span(telemetry.SPAN_BATCHER_HANDOFF):
+                        p.on_done(p.results)
                 except Exception:  # noqa: BLE001 - never kill the loop
                     telemetry.count("batcher.handoff_errors")

@@ -1179,7 +1179,7 @@ class NativeServeChain:
     def _submit_segment(self, i0: int, i1: int, tok0: int, seg_toks: int,
                         blob: bytes, text: Optional[str],
                         offs: List[int], t_drain: float) -> None:
-        with telemetry.span(telemetry.SPAN_NATIVE_DRAIN):
+        with telemetry.span(telemetry.SPAN_NATIVE_DRAIN, cpu=True):
             if text is not None:
                 tokens = [text[offs[k]: offs[k + 1]]
                           for k in range(tok0, tok0 + seg_toks)]
@@ -1356,21 +1356,22 @@ class NativeServeChain:
             return
         # Verdict-cache consult BEFORE the batcher (admitted tokens
         # only — throttled traffic must not warm or read the cache).
-        hits, miss_idx, digs = vc.lookup_batch(tokens_v,
-                                               digests=dig_list)
-        # per-tenant cache accounting (the capstat ledger's hit%
-        # column): reader-classified slots when the plane runs, the
-        # Python classifier on the plane-less fallback arm
-        if telemetry.active() is not None:
-            if tens is not None:
-                tens_v = (tens if verify_idx is None
-                          else tens[np.asarray(verify_idx,
-                                               np.intp)])
-                cache_labels = _decision.tenant_labels_from_slots(
-                    tens_v)
-            else:
-                cache_labels = _decision.tenant_labels(tokens_v)
-            _decision.count_tenant_cache(cache_labels, miss_idx)
+        with telemetry.span(telemetry.SPAN_VCACHE_LOOKUP):
+            hits, miss_idx, digs = vc.lookup_batch(tokens_v,
+                                                   digests=dig_list)
+            # per-tenant cache accounting (the capstat ledger's hit%
+            # column): reader-classified slots when the plane runs,
+            # the Python classifier on the plane-less fallback arm
+            if telemetry.active() is not None:
+                if tens is not None:
+                    tens_v = (tens if verify_idx is None
+                              else tens[np.asarray(verify_idx,
+                                                   np.intp)])
+                    cache_labels = _decision.tenant_labels_from_slots(
+                        tens_v)
+                else:
+                    cache_labels = _decision.tenant_labels(tokens_v)
+                _decision.count_tenant_cache(cache_labels, miss_idx)
         if not miss_idx:
             # every token answered from cache: encode + fold directly,
             # no batcher round-trip (memory-speed path)
@@ -1380,8 +1381,9 @@ class NativeServeChain:
             epoch0 = vc.epoch
 
             def on_done_fill(fresh: List[Any]) -> None:
-                vc.insert_batch(digs, fresh, tokens=tokens_v,
-                                epoch=epoch0)
+                with telemetry.span(telemetry.SPAN_VCACHE_INSERT):
+                    vc.insert_batch(digs, fresh, tokens=tokens_v,
+                                    epoch=epoch0)
                 on_done(fresh)
 
             self._batcher.submit_handoff(
@@ -1392,8 +1394,9 @@ class NativeServeChain:
         miss_tokens = [tokens_v[i] for i in miss_idx]
 
         def on_done_merge(fresh: List[Any]) -> None:
-            vc.insert_batch([digs[i] for i in miss_idx], fresh,
-                            tokens=miss_tokens, epoch=epoch0)
+            with telemetry.span(telemetry.SPAN_VCACHE_INSERT):
+                vc.insert_batch([digs[i] for i in miss_idx], fresh,
+                                tokens=miss_tokens, epoch=epoch0)
             full = hits
             for j, i in enumerate(miss_idx):
                 full[i] = fresh[j]

@@ -23,6 +23,10 @@ faults — real instrumentation is required. This module provides:
   threads), and the CVB1 trace-context frame field
   (:mod:`cap_tpu.serve.protocol` types 9/10) to cross process
   boundaries;
+- the **profiler clock**: once the engine installs the hook
+  (:func:`install_profiler`), every :func:`span` is also a host event
+  of the same name while a JAX profile captures, so a trace shows the
+  host stages beside the device ops;
 - a **flight recorder**: a bounded ring of completed request
   timelines, from which the slowest recent requests can be replayed
   span by span (the worker's ``/flight`` endpoint, ``tools/capstat.py
@@ -36,8 +40,9 @@ rejects anything token-shaped), and span notes pass through
 :func:`scrub_note`.
 
 Telemetry is off by default (zero overhead beyond one attribute check
-on the hot path); enable with ``telemetry.enable()`` or scoped via
-``telemetry.recording()``.
+on the hot path, plus one ``is_enabled()`` call per span once the
+profiler hook is installed); enable with ``telemetry.enable()`` or
+scoped via ``telemetry.recording()``.
 """
 
 from __future__ import annotations
@@ -73,6 +78,16 @@ SPAN_NATIVE_DRAIN = "serve.native.drain"    # ring drain -> batcher submit
 SPAN_NATIVE_POST = "serve.native.post"      # verdicts -> native writers
 SPAN_SHM_ATTACH = "serve.shm.attach"        # shm region map + negotiate
 SPAN_OIDC_VALIDATE = "oidc.claims_validate"  # raw-batch claims rules
+SPAN_BATCHER_HANDOFF = "batcher.handoff"    # verdicts -> submitter on_done
+SPAN_VCACHE_LOOKUP = "vcache.lookup"        # verdict-cache batch consult
+SPAN_VCACHE_INSERT = "vcache.insert"        # verdict-cache batch fill
+SPAN_KEYSET_NORMALIZE = "keyset.normalize"  # serialization normalize loop
+SPAN_KEYSET_ROUTE = "keyset.route"          # tokens -> family, key-table row
+SPAN_KEYSET_PACK = "keyset.pack"            # one chunk's device records
+SPAN_KEYSET_LAUNCH = "keyset.launch"        # H2D put + program enqueue
+SPAN_KEYSET_WAIT = "keyset.wait"            # host blocks on device verdicts
+SPAN_KEYSET_FINISH = "keyset.finish"        # verdict bits -> per-token answers
+SPAN_KEYSET_DECISIONS = "keyset.decisions"  # tpu-surface decision fold
 SPAN_ENGINE_PREFIX = "dispatch."            # dispatch.<family>.<detail>
 
 SPAN_NAMES = frozenset({
@@ -81,8 +96,15 @@ SPAN_NAMES = frozenset({
     SPAN_BATCHER_FILL, SPAN_BATCHER_FLUSH, SPAN_BATCHER_DISPATCH,
     SPAN_BATCHER_COLLECT, SPAN_KEYPLANE_SWAP, SPAN_NATIVE_DRAIN,
     SPAN_NATIVE_POST, SPAN_SHM_ATTACH, SPAN_OIDC_VALIDATE,
-    SPAN_FRONTDOOR_ROUTE, SPAN_FRONTDOOR_RELAY,
+    SPAN_FRONTDOOR_ROUTE, SPAN_FRONTDOOR_RELAY, SPAN_BATCHER_HANDOFF,
+    SPAN_VCACHE_LOOKUP, SPAN_VCACHE_INSERT, SPAN_KEYSET_NORMALIZE,
+    SPAN_KEYSET_ROUTE, SPAN_KEYSET_PACK, SPAN_KEYSET_LAUNCH,
+    SPAN_KEYSET_WAIT, SPAN_KEYSET_FINISH, SPAN_KEYSET_DECISIONS,
 })
+
+# Suffix of the series a ``span(..., cpu=True)`` records the thread's
+# CPU seconds into (``batcher.dispatch.cpu``).
+CPU_SUFFIX = ".cpu"
 
 # ---------------------------------------------------------------------------
 # histogram buckets: log-scale, fixed at import time
@@ -331,18 +353,34 @@ class Recorder:
             h.add_many(float(value), k)
 
     @contextmanager
-    def span(self, name: str, note: Optional[str] = None) -> Iterator[None]:
+    def span(self, name: str, note: Optional[str] = None,
+             cpu: bool = False) -> Iterator[None]:
         """Time a block; the duration lands in the ``name`` series (s).
         When a trace context is active, a span record is attached to
-        the trace(s) as well."""
+        the trace(s) as well; while a profiler captures, the block is
+        also a host event named ``name`` on its timeline.
+
+        ``cpu``: also record the calling thread's CPU seconds in the
+        block into ``<name>.cpu``. Wall minus CPU is time the thread
+        waited: for the GIL, or in a blocking call."""
         traces = _trace_ctx.get()
+        ann = _annotation
+        event = ann(name) if ann is not None and ann.is_enabled() else None
+        if event is not None:
+            event.__enter__()
         t0_wall = time.time()
         t0 = time.perf_counter()
+        c0 = time.thread_time() if cpu else 0.0
         try:
             yield
         finally:
+            c1 = time.thread_time() if cpu else 0.0
             dur = time.perf_counter() - t0
+            if event is not None:
+                event.__exit__(None, None, None)
             self.observe(name, dur)
+            if cpu:
+                self.observe(name + CPU_SUFFIX, c1 - c0)
             if traces is not None:
                 self.trace_span(traces, name, t0_wall, dur, note=note)
 
@@ -574,6 +612,22 @@ def trace_scope(trace_ids: Sequence[str]) -> Iterator[None]:
 
 _recorder: Optional[Recorder] = None
 
+# The profiler's host-event class (``jax.profiler.TraceAnnotation``), or
+# None. The engine side installs it once per process
+# (``cap_tpu.jwt.tpu_keyset``): this module never imports JAX, so
+# clients, the front door and the tools pay nothing for it. The
+# profiler is process-wide, so the hook is too.
+_annotation: Optional[Any] = None
+
+
+def install_profiler(annotation_cls: Any) -> None:
+    """Put every :func:`span` on the profiler's clock: while a profile
+    captures, ``span(name)`` also opens ``annotation_cls(name)``, a host
+    event with the registered span name. ``annotation_cls`` needs a
+    static ``is_enabled()`` and the context-manager protocol."""
+    global _annotation
+    _annotation = annotation_cls
+
 
 def enable(recorder: Optional[Recorder] = None) -> Recorder:
     """Turn telemetry on (idempotent); returns the active recorder."""
@@ -642,10 +696,19 @@ def flight(trace_id: str, total_s: float,
 
 
 @contextmanager
-def span(name: str, note: Optional[str] = None) -> Iterator[None]:
+def span(name: str, note: Optional[str] = None,
+         cpu: bool = False) -> Iterator[None]:
+    """:meth:`Recorder.span` on the live recorder. With telemetry off
+    the block is still a profiler host event while a profile captures,
+    and costs one ``is_enabled()`` check when none does."""
     rec = _recorder
-    if rec is None:
+    if rec is not None:
+        with rec.span(name, note=note, cpu=cpu):
+            yield
+        return
+    ann = _annotation
+    if ann is None or not ann.is_enabled():
         yield
         return
-    with rec.span(name, note=note):
+    with ann(name):
         yield

@@ -19,3 +19,14 @@ stored **limb-first**: an array of shape [K, N] holds N numbers of K
 limbs, so the batch axis N rides the TPU's 128-wide vector lanes and
 limb shifts are cheap sublane moves.
 """
+
+
+def program_name(name: str):
+    """Decorator: give a function the stable name ``name``. JAX names a
+    jitted program after its function (the HLO module ``jit_<name>``,
+    its profile events, its compile events), so a program named by a
+    module constant keeps that name through any refactor."""
+    def rename(fn):
+        fn.__name__ = fn.__qualname__ = name
+        return fn
+    return rename

@@ -41,6 +41,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from . import limbs as L
+from . import program_name
 
 
 def ladder_mode() -> str:
@@ -924,6 +925,10 @@ def verify_ecdsa_batch(table: ECKeyTable, sigs: Sequence[bytes],
 # ---------------------------------------------------------------------------
 
 ES_REC_EXTRA = 2          # trailing bytes per record: flags, key row
+# Stable name of the packed ES* program (the jitted functions below are
+# named by it): a profile's module names contain it, so a reader of the
+# family's device time finds its program after any refactor.
+PROGRAM = "es_packed"
 
 
 def es_packed_records(table: ECKeyTable, sig_mat: np.ndarray,
@@ -946,6 +951,7 @@ def es_packed_records(table: ECKeyTable, sig_mat: np.ndarray,
     return rec
 
 
+@program_name(f"{PROGRAM}_rns")
 def _es_packed_rns_impl(packed, tab, consts, *, crv: str,
                         nbits: int, wbits: int, k: int, cb: int,
                         hlen: int, ladder: str = "jacobian"):
@@ -962,6 +968,7 @@ def _es_packed_rns_impl(packed, tab, consts, *, crv: str,
     return ok & flags, deg & flags
 
 
+@program_name(f"{PROGRAM}_limb")
 def _es_packed_limb_impl(packed, tqx, tqy, g_tabs, consts, *, nbits: int,
                          n_windows: int, k: int, cb: int, hlen: int,
                          pbits: int = 0, ladder: str = "jacobian"):

@@ -28,6 +28,13 @@ from typing import List, Optional, Sequence
 import numpy as np
 
 from . import limbs as L
+from . import program_name
+
+# Stable names of the packed programs (the jitted functions below are
+# named by them): a profile's module names contain them, so a reader of
+# per-family device time finds its program after any refactor.
+PROGRAM = "rs_packed"        # RS*: PKCS#1 v1.5
+PS_PROGRAM = "ps_packed"     # PS*: EMSA-PSS
 
 # ASN.1 DigestInfo prefixes (RFC 8017 §9.2 notes).
 DIGEST_INFO_PREFIX = {
@@ -697,6 +704,7 @@ def _rs_packed_unpack(packed, k: int, h_len: int):
     return s_limbs, dig, flags, idx
 
 
+@program_name(f"{PROGRAM}_rns")
 def _rs_packed_rns_impl(packed, sizes_tab, n_tab, sig_c_tab, n_B_tab,
                         a2_A_tab, a2_B_tab, *, k: int, hash_name: str,
                         ctx):
@@ -716,6 +724,7 @@ def _rs_packed_rns_impl(packed, sizes_tab, n_tab, sig_c_tab, n_B_tab,
     return ok & in_range & flags
 
 
+@program_name(f"{PROGRAM}_limb")
 def _rs_packed_limb_impl(packed, sizes_tab, n_tab, np_tab, r2_tab,
                          one_tab, e_tab, *, k: int, hash_name: str,
                          ebits: int, all_f4: bool):
@@ -740,6 +749,7 @@ def _rs_packed_limb_impl(packed, sizes_tab, n_tab, np_tab, r2_tab,
     return eq & in_range & flags
 
 
+@program_name(f"{PS_PROGRAM}_rns")
 def _ps_packed_rns_impl(packed, mod_bits_tab, n_tab, sig_c_tab, n_B_tab,
                         a2_A_tab, a2_B_tab, *, k: int, hash_name: str,
                         ctx):
@@ -759,6 +769,7 @@ def _ps_packed_rns_impl(packed, mod_bits_tab, n_tab, sig_c_tab, n_B_tab,
     return ok & in_range & flags
 
 
+@program_name(f"{PS_PROGRAM}_limb")
 def _ps_packed_limb_impl(packed, mod_bits_tab, n_tab, np_tab, r2_tab,
                          one_tab, e_tab, *, k: int, hash_name: str,
                          ebits: int, all_f4: bool):
