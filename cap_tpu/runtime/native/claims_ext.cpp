@@ -773,7 +773,122 @@ PyObject* registered_batch(PyObject*, PyObject* args) {
   return ret;
 }
 
+// ---------------------------------------------------------------------------
+// exp_nbf_batch: the verdict cache's clamp inputs, no Python objects
+// ---------------------------------------------------------------------------
+//
+// The verdict cache reads only the top-level "exp" and "nbf" of each
+// accepted raw payload. This walks each tape for those two keys inside
+// the GIL-free phase 1 and writes doubles, with the rules of the
+// cache's per-entry reader (vcache._claims_exp_nbf):
+//   - an int or float value is the number (int64 → double rounds to
+//     nearest, as Python's float(int) does);
+//   - a string, true/false, null, object or array is absent (NaN);
+//   - with duplicate keys the last one wins, as in json.loads.
+// Status 0 = read from the tape; ST_FALLBACK = the caller takes the
+// per-entry path: a payload that is not an ST_OK object (malformed,
+// not an object, outside the envelope), an escaped top-level key (it
+// could spell exp or nbf), or an exp/nbf that is OP_BIGINT (json.loads
+// gives an int that float() may refuse).
+
+int exp_nbf_of_tape(const TokenTape& tape, const uint8_t* payload,
+                    double* exp, double* nbf) {
+  *exp = NAN;
+  *nbf = NAN;
+  if (tape.status != ST_OK) return ST_FALLBACK;
+  const uint32_t* ops = tape.ops.data();
+  size_t nops = tape.ops.size();
+  int depth = 0;
+  double* pending = nullptr;  // the slot of the top-level key just read
+  for (size_t t = 0; t < nops; t += 3) {
+    uint32_t op = ops[t], a = ops[t + 1], b = ops[t + 2];
+    bool value = depth == 1 && pending != nullptr;
+    switch (op) {
+      case OP_OBJ_START:
+      case OP_ARR_START:
+        if (value) *pending = NAN;
+        ++depth;
+        break;
+      case OP_OBJ_END:
+      case OP_ARR_END:
+        --depth;
+        break;
+      case OP_KEY: {
+        if (depth != 1) break;
+        uint32_t len = b >> 1, esc = b & 1;
+        if (esc) return ST_FALLBACK;
+        pending = nullptr;
+        if (len == 3 && std::memcmp(payload + a, "exp", 3) == 0)
+          pending = exp;
+        else if (len == 3 && std::memcmp(payload + a, "nbf", 3) == 0)
+          pending = nbf;
+        continue;  // keep `pending` for the value that follows
+      }
+      case OP_INT:
+        if (value)
+          *pending = static_cast<double>(static_cast<int64_t>(
+              (static_cast<uint64_t>(b) << 32) | a));
+        break;
+      case OP_FLOAT:
+        if (value) {
+          uint64_t bits = (static_cast<uint64_t>(b) << 32) | a;
+          std::memcpy(pending, &bits, 8);
+        }
+        break;
+      case OP_BIGINT:
+        if (value) return ST_FALLBACK;
+        break;
+      default:  // OP_STR, OP_TRUE, OP_FALSE, OP_NULL
+        if (value) *pending = NAN;
+        break;
+    }
+    if (depth <= 1) pending = nullptr;  // a top-level value was consumed
+  }
+  return ST_OK;
+}
+
+// exp_nbf_batch(scratch, offsets_i64, lengths_i64, n_threads=0)
+//   -> (exp bytearray, nbf bytearray, status bytearray): float64 exp and
+// nbf per payload (NaN = absent) and one status byte per payload.
+PyObject* exp_nbf_batch(PyObject*, PyObject* args) {
+  Py_buffer scratch, offv, lenv;
+  int n_threads = 0;
+  if (!PyArg_ParseTuple(args, "y*y*y*|i", &scratch, &offv, &lenv,
+                        &n_threads))
+    return nullptr;
+  const uint8_t* base = static_cast<const uint8_t*>(scratch.buf);
+  const int64_t* offs = static_cast<const int64_t*>(offv.buf);
+  Py_ssize_t n = offv.len / static_cast<Py_ssize_t>(sizeof(int64_t));
+
+  PyObject* exp_out = PyByteArray_FromStringAndSize(nullptr, n * 8);
+  PyObject* nbf_out = PyByteArray_FromStringAndSize(nullptr, n * 8);
+  PyObject* st_out = PyByteArray_FromStringAndSize(nullptr, n);
+  bool ok = exp_out != nullptr && nbf_out != nullptr && st_out != nullptr;
+  if (ok) {
+    double* exps = reinterpret_cast<double*>(PyByteArray_AS_STRING(exp_out));
+    double* nbfs = reinterpret_cast<double*>(PyByteArray_AS_STRING(nbf_out));
+    uint8_t* st = reinterpret_cast<uint8_t*>(PyByteArray_AS_STRING(st_out));
+    ok = run_phase1(&scratch, &offv, &lenv, n_threads,
+                    [&](size_t i, TokenTape&& tape) {
+                      st[i] = static_cast<uint8_t>(exp_nbf_of_tape(
+                          tape, base + offs[i], &exps[i], &nbfs[i]));
+                    });
+  }
+  PyBuffer_Release(&scratch);
+  PyBuffer_Release(&offv);
+  PyBuffer_Release(&lenv);
+  PyObject* ret = ok ? PyTuple_Pack(3, exp_out, nbf_out, st_out) : nullptr;
+  Py_XDECREF(exp_out);
+  Py_XDECREF(nbf_out);
+  Py_XDECREF(st_out);
+  return ret;
+}
+
 PyMethodDef methods[] = {
+    {"exp_nbf_batch", exp_nbf_batch, METH_VARARGS,
+     "exp_nbf_batch(scratch, offsets_i64, lengths_i64, n_threads=0) -> "
+     "(exp, nbf, status) bytearrays: float64 top-level exp/nbf per "
+     "payload (NaN = absent), status 0 read / 3 take the per-entry path"},
     {"parse_batch", parse_batch, METH_VARARGS,
      "parse_batch(scratch, offsets_i64, lengths_i64, n_threads=0) -> "
      "(list[dict | int-status], n_bad)"},

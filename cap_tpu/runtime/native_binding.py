@@ -271,6 +271,28 @@ def registered_claims_from_payloads(payloads: Sequence[bytes]):
             for i, v in enumerate(parsed)]
 
 
+def exp_nbf_from_payloads(payloads: Sequence[bytes]):
+    """[raw payload bytes] → ``(exp, nbf, status)`` numpy arrays, or
+    None when the extension is absent.
+
+    ``exp``/``nbf`` are float64 (NaN = absent) read by the native tape
+    scan from each payload's top level; ``status`` is 0 where they
+    were read and nonzero where the caller must read that payload
+    itself (the rules are in claims_ext.cpp's ``exp_nbf_batch``).
+    The scan runs with the GIL released and makes no Python object
+    per payload."""
+    if _claims_ext is None or not hasattr(_claims_ext, "exp_nbf_batch"):
+        return None
+    n = len(payloads)
+    lens = np.fromiter(map(len, payloads), np.int64, count=n)
+    offs = np.zeros(n, np.int64)
+    np.cumsum(lens[:-1], out=offs[1:])
+    exp, nbf, status = _claims_ext.exp_nbf_batch(b"".join(payloads),
+                                                 offs, lens)
+    return (np.frombuffer(exp, np.float64), np.frombuffer(nbf, np.float64),
+            np.frombuffer(status, np.uint8))
+
+
 def _copy_claims(v):
     """Independent copy of a parsed-JSON value (containers only)."""
     if isinstance(v, dict):

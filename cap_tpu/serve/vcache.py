@@ -36,10 +36,11 @@ change how fast a verdict is produced, never which verdict. A final
 re-validation at serve time backs this with a tripwire counter
 (``vcache.stale_accepts``, SLO-pinned to 0).
 
-Counters (one ``count_many`` lock round per batched lookup):
+Counters (one ``count_many`` lock round per batched lookup or fill):
 ``vcache.lookups == vcache.hits + vcache.misses`` exactly
-(obs-smoke gates this), plus inserts / evictions / epoch_bumps /
-clamp_drops, and a ``vcache.size`` gauge on the worker scrape.
+(obs-smoke gates this), plus inserts / evictions / fill_fallbacks /
+epoch_bumps / clamp_drops, and a ``vcache.size`` gauge on the worker
+scrape.
 """
 
 from __future__ import annotations
@@ -50,7 +51,12 @@ import hashlib
 import json
 import threading
 import time
+from collections import deque
+from itertools import islice, repeat
+from operator import itemgetter
 from typing import Any, Dict, List, Optional, Sequence, Tuple
+
+import numpy as np
 
 from .. import telemetry
 from ..obs import decision as _decision
@@ -102,12 +108,33 @@ def _claims_exp_nbf(verdict: Any, token: Any) -> Tuple[Optional[float],
                 claims = None
     if not isinstance(claims, dict):
         return (None, None)
-
-    def _num(v):
-        return float(v) if isinstance(v, (int, float)) \
-            and not isinstance(v, bool) else None
-
     return (_num(claims.get("exp")), _num(claims.get("nbf")))
+
+
+def _num(v: Any) -> Optional[float]:
+    # float() of an int too large for a double raises OverflowError
+    return float(v) if isinstance(v, (int, float)) \
+        and not isinstance(v, bool) else None
+
+
+def _native_exp_nbf():
+    """The native exp/nbf scan over raw payloads
+    (``native_binding.exp_nbf_from_payloads``), or None where the
+    native runtime is unbuilt."""
+    from ..runtime.prep import _load_native
+
+    native = _load_native()
+    return None if native is None else native.exp_nbf_from_payloads
+
+
+_first_byte = itemgetter(0)
+
+
+def _take(seq: Sequence[Any], idx: List[int]) -> tuple:
+    """``seq[i] for i in idx`` as a tuple, at C speed."""
+    if len(idx) < 2:
+        return tuple(seq[i] for i in idx)
+    return itemgetter(*idx)(seq)
 
 
 # Cache entries are plain tuples — the lookup hot loop indexes them
@@ -147,6 +174,7 @@ class VerdictCache:
         self._ctr = {"vcache.lookups": 0, "vcache.hits": 0,
                      "vcache.misses": 0, "vcache.inserts": 0,
                      "vcache.insert_skips": 0, "vcache.evictions": 0,
+                     "vcache.fill_fallbacks": 0,
                      "vcache.epoch_bumps": 0, "vcache.clamp_drops": 0,
                      "vcache.stale_accepts": 0,
                      "vcache.peer_fills": 0,
@@ -312,53 +340,172 @@ class VerdictCache:
             return _decision.classify(verdict) in CACHEABLE_REJECTS
         return True
 
+    def _clamp_inputs(self, verdicts: Sequence[Any],
+                      tokens: Optional[Sequence[Any]]) -> tuple:
+        """Per verdict: ``exp``/``nbf`` (float64 arrays, with
+        ``has_exp``/``has_nbf`` saying which are present) and ``keep``
+        (cacheable, and no exp/nbf too large for a float); plus how
+        many verdicts took the per-entry reader.
+
+        Raw-payload (``bytes``) verdicts are read by one native scan;
+        what it declines, and any other verdict type, goes through
+        :func:`_claims_exp_nbf` one by one — all of them where the
+        native runtime is unbuilt. Dicts are read directly, and
+        exceptions carry no exp/nbf."""
+        n = len(verdicts)
+        payloads = [v if type(v) is bytes else b"" for v in verdicts]
+        scan = _native_exp_nbf() if any(payloads) else None
+        found = None if scan is None else scan(payloads)
+        if found is None:
+            exp, nbf = np.full(n, np.nan), np.full(n, np.nan)
+            redo = range(n)
+        else:
+            exp, nbf, status = found
+            redo = np.flatnonzero(status).tolist()
+        has_exp, has_nbf = ~np.isnan(exp), ~np.isnan(nbf)
+        keep = np.ones(n, bool)
+        fallbacks = 0
+        for i in redo:
+            v = verdicts[i]
+            if isinstance(v, BaseException):
+                keep[i] = self.cacheable(v)
+                continue
+            if not isinstance(v, dict):
+                fallbacks += 1
+            try:
+                e, f = _claims_exp_nbf(
+                    v, tokens[i] if tokens is not None else None)
+            except OverflowError:
+                keep[i] = False     # served, just not cached
+                continue
+            has_exp[i], has_nbf[i] = e is not None, f is not None
+            exp[i] = np.nan if e is None else e
+            nbf[i] = np.nan if f is None else f
+        return exp, has_exp, nbf, has_nbf, keep, fallbacks
+
     def insert(self, digest: bytes, verdict: Any, token: Any = None,
                epoch: Optional[int] = None,
                now: Optional[float] = None) -> bool:
         """Insert one verdict; returns False (counted as a skip) when
         the verdict class is uncacheable, the entry is already expired,
         or ``epoch`` no longer matches the cache epoch (the verify
-        raced a rotation — conservative drop)."""
-        if now is None:
-            now = time.time()
-        if not self.cacheable(verdict) or epoch != self._epoch:
-            self._count({"vcache.insert_skips": 1})
-            return False
-        exp, nbf = _claims_exp_nbf(verdict, token) \
-            if not isinstance(verdict, BaseException) else (None, None)
-        if exp is not None and now >= exp:
-            self._count({"vcache.insert_skips": 1})
-            return False
-        until = now + self._max_ttl
-        if exp is not None and exp < until:
-            until = exp
-        e = (verdict, nbf if nbf is not None else 0.0, until, epoch,
-             exp)
-        s = digest[0] & (self._n_shards - 1)
-        evicted = 0
-        with self._locks[s]:
-            shard = self._shards[s]
-            if digest not in shard and len(shard) >= self._cap_per_shard:
-                # bounded: evict the oldest inserted (dict order)
-                shard.pop(next(iter(shard)))
-                evicted = 1
-            shard[digest] = e
-        self._count({"vcache.inserts": 1, "vcache.evictions": evicted})
-        return True
+        raced a rotation — conservative drop). A one-entry
+        :meth:`insert_batch`."""
+        return self.insert_batch([digest], [verdict],
+                                 None if token is None else [token],
+                                 epoch=epoch, now=now) == 1
 
     def insert_batch(self, digests: Sequence[bytes],
                      verdicts: Sequence[Any],
                      tokens: Optional[Sequence[Any]] = None,
-                     epoch: Optional[int] = None) -> int:
-        now = time.time()
-        n_in = 0
-        for i, d in enumerate(digests):
-            if self.insert(d, verdicts[i],
-                           token=tokens[i] if tokens is not None
-                           else None,
-                           epoch=epoch, now=now):
-                n_in += 1
-        return n_in
+                     epoch: Optional[int] = None,
+                     now: Optional[float] = None) -> int:
+        """Insert a batch of fresh verdicts; returns how many were
+        cached. Each entry is judged as :meth:`insert` judges one —
+        uncacheable, already expired, or (for the whole batch) an
+        ``epoch`` that no longer matches is a skip — and an ``exp`` or
+        ``nbf`` too large for a float skips that entry too.
+
+        One clock read, one epoch check, one native exp/nbf scan over
+        the raw-payload verdicts, each shard lock taken once, and one
+        counter fold per batch."""
+        n = len(digests)
+        if not n:
+            return 0
+        if now is None:
+            now = time.time()
+        if epoch != self._epoch:
+            self._count({"vcache.insert_skips": n})
+            return 0
+        exp, has_exp, nbf, has_nbf, keep, fallbacks = self._clamp_inputs(
+            verdicts, tokens)
+        # skip where now >= exp: NaN (absent) compares False, as a
+        # skipped `exp is not None` test does
+        keep &= ~(exp <= now)
+        sel = np.flatnonzero(keep)
+        evicted = 0
+        if len(sel):
+            # valid_until = min(now + max_ttl, exp); fmin ignores NaN
+            until = np.fmin(exp, now + self._max_ttl)
+            valid_from = np.where(has_nbf, nbf, 0.0)
+            if len(sel) < n:
+                idx = sel.tolist()
+                digests, verdicts = _take(digests, idx), _take(verdicts, idx)
+                until, valid_from = until[sel], valid_from[sel]
+                exp, has_exp = exp[sel], has_exp[sel]
+            exps = exp.tolist()
+            for i in np.flatnonzero(~has_exp).tolist():
+                exps[i] = None
+            evicted = self._fill(digests, list(zip(
+                verdicts, valid_from.tolist(), until.tolist(),
+                repeat(epoch), exps)))
+        self._count({"vcache.inserts": len(sel),
+                     "vcache.insert_skips": n - len(sel),
+                     "vcache.evictions": evicted,
+                     "vcache.fill_fallbacks": fallbacks})
+        return len(sel)
+
+    def _fill(self, digests: Sequence[bytes],
+              entries: Sequence[tuple]) -> int:
+        """Install ``entries`` under ``digests`` in order, grouped by
+        shard (each lock taken once); returns the evictions."""
+        mask = self._n_shards - 1
+        shard_of = np.fromiter(map(_first_byte, digests), np.intp,
+                               count=len(digests)) & mask
+        order = np.argsort(shard_of, kind="stable")
+        bounds = np.searchsorted(shard_of[order],
+                                 np.arange(self._n_shards + 1)).tolist()
+        idx = order.tolist()
+        digests, entries = _take(digests, idx), _take(entries, idx)
+        evicted = 0
+        for s in range(self._n_shards):
+            lo, hi = bounds[s], bounds[s + 1]
+            if lo < hi:
+                with self._locks[s]:
+                    evicted += self._fill_shard(
+                        self._shards[s], digests[lo:hi], entries[lo:hi])
+        return evicted
+
+    def _fill_shard(self, shard: Dict[bytes, tuple],
+                    digests: Sequence[bytes],
+                    entries: Sequence[tuple]) -> int:
+        """Exactly what inserting one entry at a time does: a digest
+        already cached keeps its place and takes the new entry; a new
+        one into a full shard evicts the oldest inserted. Returns the
+        evictions. Caller holds the shard's lock.
+
+        ``update`` alone is that, up to capacity: each digest's last
+        entry, cached ones in place, new ones appended in order."""
+        cap = self._cap_per_shard
+        before = len(shard)
+        shard.update(zip(digests, entries))
+        over = len(shard) - cap
+        if over <= 0:
+            return 0
+        if len(shard) - before == len(digests):
+            # all new and distinct: the oldest `over` go, in one pass
+            # (it walks the dict's deleted slots once per batch)
+            for d in list(islice(shard, over)):
+                del shard[d]
+            return over
+        # repeats, or digests cached already, while evicting: one at a
+        # time, a digest evicted before it comes again is new again.
+        # Replay that order on the keys (the first `before` are the
+        # shard's old order), then rebuild the shard in it.
+        order = deque(islice(shard, before))
+        live = set(order)
+        evicted = 0
+        for d in digests:
+            if d not in live:
+                if len(live) >= cap:
+                    live.discard(order.popleft())
+                    evicted += 1
+                order.append(d)
+                live.add(d)
+        kept = [(d, shard[d]) for d in order]
+        shard.clear()
+        shard.update(kept)
+        return evicted
 
     # -- peer fill (fleet cache warming, CVB1 frame pair 13/14) -----------
 
@@ -429,9 +576,8 @@ class VerdictCache:
         if epoch != self._epoch:
             self._count({"vcache.peer_fill_skips": len(entries)})
             return 0
-        filled = 0
-        skipped = 0
-        evicted = 0
+        digests: List[bytes] = []
+        rows: List[tuple] = []
         for row in entries:
             try:
                 digest = bytes.fromhex(row[0])
@@ -441,34 +587,23 @@ class VerdictCache:
                 exp = float(row[4]) if row[4] is not None else None
             except (ValueError, TypeError, IndexError,
                     binascii.Error):
-                skipped += 1
                 continue
             until = min(valid_until, now + self._max_ttl)
             if exp is not None:
                 until = min(until, exp)
             if len(digest) != DIGEST_LEN or now >= until:
-                skipped += 1
                 continue
-            # re-check the epoch per entry: a rotation landing mid-
-            # import invalidates the REST of the transfer, not just
-            # the next lookup
-            if epoch != self._epoch:
-                skipped += len(entries) - filled - skipped
-                break
-            e = (payload, valid_from, until, epoch, exp)
-            s = digest[0] & (self._n_shards - 1)
-            with self._locks[s]:
-                shard = self._shards[s]
-                if digest not in shard \
-                        and len(shard) >= self._cap_per_shard:
-                    shard.pop(next(iter(shard)))
-                    evicted += 1
-                shard[digest] = e
-            filled += 1
-        self._count({"vcache.peer_fills": filled,
-                     "vcache.peer_fill_skips": skipped,
+            digests.append(digest)
+            rows.append((payload, valid_from, until, epoch, exp))
+        # re-check the epoch after the parse: a rotation landing
+        # mid-import drops the whole transfer, not just the next lookup
+        if epoch != self._epoch:
+            digests, rows = [], []
+        evicted = self._fill(digests, rows) if digests else 0
+        self._count({"vcache.peer_fills": len(digests),
+                     "vcache.peer_fill_skips": len(entries) - len(digests),
                      "vcache.evictions": evicted})
-        return filled
+        return len(digests)
 
     # -- stats ------------------------------------------------------------
 

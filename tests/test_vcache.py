@@ -202,6 +202,286 @@ def test_lookup_batch_uses_supplied_digests_and_falls_back():
 
 
 # ---------------------------------------------------------------------------
+# the batched fill against the per-entry insert it replaced
+# ---------------------------------------------------------------------------
+
+NOW = 1_700_000_000.0
+FUT = NOW + 100
+PAST = NOW - 1
+
+
+def _ref_exp_nbf(verdict, token):
+    """The per-entry exp/nbf reader, frozen as the batched fill's
+    reference."""
+    claims = None
+    if isinstance(verdict, dict):
+        claims = verdict
+    elif isinstance(verdict, (bytes, bytearray, memoryview)):
+        try:
+            claims = json.loads(bytes(verdict))
+        except (ValueError, UnicodeDecodeError):
+            claims = None
+    if claims is None and isinstance(token, str):
+        parts = token.split(".")
+        if len(parts) >= 2:
+            seg = parts[1]
+            try:
+                pad = "=" * (-len(seg) % 4)
+                claims = json.loads(base64.urlsafe_b64decode(seg + pad))
+            except (ValueError, UnicodeDecodeError):
+                claims = None
+    if not isinstance(claims, dict):
+        return (None, None)
+
+    def _num(v):
+        return float(v) if isinstance(v, (int, float)) \
+            and not isinstance(v, bool) else None
+
+    return (_num(claims.get("exp")), _num(claims.get("nbf")))
+
+
+def _ref_insert(vc, digest, verdict, token, epoch, now):
+    """The per-entry insert, frozen: one counter fold, one lock and
+    one eviction scan per entry."""
+    if not vc.cacheable(verdict) or epoch != vc._epoch:
+        vc._count({"vcache.insert_skips": 1})
+        return False
+    exp, nbf = _ref_exp_nbf(verdict, token) \
+        if not isinstance(verdict, BaseException) else (None, None)
+    if exp is not None and now >= exp:
+        vc._count({"vcache.insert_skips": 1})
+        return False
+    until = now + vc._max_ttl
+    if exp is not None and exp < until:
+        until = exp
+    e = (verdict, nbf if nbf is not None else 0.0, until, epoch, exp)
+    s = digest[0] & (vc._n_shards - 1)
+    evicted = 0
+    with vc._locks[s]:
+        shard = vc._shards[s]
+        if digest not in shard and len(shard) >= vc._cap_per_shard:
+            shard.pop(next(iter(shard)))
+            evicted = 1
+        shard[digest] = e
+    vc._count({"vcache.inserts": 1, "vcache.evictions": evicted})
+    return True
+
+
+def _snapshot(vc):
+    """Every shard's entries in order, and the counters the reference
+    keeps; repr, so a NaN exp compares equal to itself."""
+    st = vc.stats()
+    st.pop("vcache.fill_fallbacks")
+    return repr([list(s.items()) for s in vc._shards]), st
+
+
+def _tok_with(**claims):
+    return f"h.{_payload(claims)}.s"
+
+
+_BIG = b"9" * 400      # an int json.loads reads and float() refuses
+
+FILL_CASES = {
+    "int_exp_nbf": [b'{"sub":"a","exp":%d,"nbf":%d}' % (FUT, PAST)],
+    "float_exp": [b'{"exp":%r,"nbf":%r}' % (FUT + 0.25, PAST - 0.5)],
+    "exp_1e400": [b'{"exp":1e400}', b'{"exp":-1e400}'],
+    "exp_true": [b'{"exp":true}', b'{"exp":false,"nbf":true}'],
+    "exp_string": [b'{"exp":"%d"}' % PAST],
+    "exp_null": [b'{"exp":null,"nbf":null}'],
+    "exp_absent": [b'{"sub":"x"}', b"{}"],
+    "exp_container": [b'{"exp":[%d],"nbf":{"t":%d}}' % (PAST, FUT)],
+    "duplicate_exp": [b'{"exp":%d,"exp":%d}' % (PAST, FUT),
+                      b'{"exp":%d,"exp":%d}' % (FUT, PAST),
+                      b'{"exp":%d,"exp":"x"}' % PAST],
+    "escaped_exp_key": [b'{"e\\u0078p":%d}' % PAST,
+                        b'{"\\u006ebf":%d,"exp":%d}' % (FUT, FUT)],
+    "nested_exp": [b'{"a":{"exp":%d,"nbf":%d}}' % (PAST, FUT)],
+    "non_object": [(b"[1,2]", _tok_with(exp=PAST)), b'"s"', b"7"],
+    "invalid_bytes_token_segment": [
+        (b"not-json", _tok_with(exp=PAST)),
+        (b"{bad", _tok_with(exp=FUT, nbf=FUT)), (b"", "a.b.c")],
+    "bigint_exp": [b'{"exp":1%s}' % (b"0" * 20),
+                   b'{"nbf":-1%s}' % (b"0" * 30)],
+    "nan_exp": [b'{"exp":NaN}', b'{"nbf":NaN,"exp":%d}' % FUT],
+    "dict_verdicts": [{"sub": "d", "exp": FUT, "nbf": PAST},
+                      {"exp": PAST}, {"exp": True}, {"exp": FUT + 0.5},
+                      {"sub": "no-exp"}],
+    "exceptions": [InvalidSignatureError("bad"), MalformedTokenError("m"),
+                   UnknownKeyIDError("k"), TimeoutError("t")],
+    "already_expired": [b'{"exp":%d}' % PAST, b'{"exp":%r}' % NOW],
+    "nbf_in_future": [b'{"exp":%d,"nbf":%d}' % (FUT, FUT - 1)],
+    "overflow": [b'{"exp":%s}' % _BIG, b'{"nbf":-%s}' % _BIG,
+                 {"exp": int(_BIG)}, b'{"exp":%d}' % FUT],
+}
+
+
+def _ref_insert_all(vc, digests, verdicts, tokens, epoch):
+    n = 0
+    for d, v, t in zip(digests, verdicts, tokens):
+        try:
+            n += _ref_insert(vc, d, v, t, epoch, NOW)
+        except OverflowError:
+            # the one repair: the per-entry insert raised out of the
+            # whole batch here; the fill skips that entry instead
+            vc._count({"vcache.insert_skips": 1})
+    return n
+
+
+@pytest.fixture(params=["native", "no_extension"])
+def fill_mode(request, monkeypatch):
+    if request.param == "native":
+        assert V._native_exp_nbf() is not None, "native runtime unbuilt"
+    else:
+        monkeypatch.setattr(V, "_native_exp_nbf", lambda: None)
+    return request.param
+
+
+@pytest.mark.parametrize("case", sorted(FILL_CASES))
+def test_insert_batch_matches_per_entry_insert(case, fill_mode):
+    """Three batches into a small cache, each filled both ways: eight
+    fillers (the shards fill up), the case's entries (all new while
+    full: the one-pass eviction), then fillers and the case again
+    (cached digests and repeats while full: the replayed order)."""
+    case_items = [v if isinstance(v, tuple) else (v, _tok_with(exp=PAST))
+                  for v in FILL_CASES[case]]
+    keys = [f"{case}-{i}" for i in range(len(case_items))]
+    fill = [(b'{"exp":%d}' % FUT, f"a.b{i}.c") for i in range(8)]
+    fill_keys = [f"fill-{i}" for i in range(8)]
+    ref = V.VerdictCache(capacity=8, shards=2, max_ttl_s=300)
+    got = V.VerdictCache(capacity=8, shards=2, max_ttl_s=300)
+    for vc in (ref, got):
+        vc.set_epoch(3)
+    for ks, items in ((fill_keys, fill), (keys, case_items),
+                      (fill_keys[:3] + keys + keys, fill[:3] + case_items
+                       + case_items)):
+        digests = [V.token_digest(k) for k in ks]
+        verdicts = [v for v, _ in items]
+        tokens = [t for _, t in items]
+        n_ref = _ref_insert_all(ref, digests, verdicts, tokens, 3)
+        assert got.insert_batch(digests, verdicts, tokens, epoch=3,
+                                now=NOW) == n_ref
+        assert _snapshot(got) == _snapshot(ref)
+
+
+@pytest.mark.parametrize("case", ["epoch_mismatch", "one_by_one"])
+def test_insert_batch_matches_per_entry_insert_whole_batch(case,
+                                                           fill_mode):
+    """A racing rotation skips the batch whole; and ``insert`` (one
+    entry of the batched path) matches the reference entry by entry."""
+    verdicts = [v for c in sorted(FILL_CASES) for v in FILL_CASES[c]]
+    verdicts = [v[0] if isinstance(v, tuple) else v for v in verdicts]
+    digests = [V.token_digest(f"w{i}") for i in range(len(verdicts))]
+    tokens = [_tok_with(exp=FUT)] * len(verdicts)
+    ref = V.VerdictCache(capacity=16, shards=4)
+    got = V.VerdictCache(capacity=16, shards=4)
+    epoch = 3 if case == "one_by_one" else 2
+    for vc in (ref, got):
+        vc.set_epoch(3)
+    _ref_insert_all(ref, digests, verdicts, tokens, epoch)
+    if case == "one_by_one":
+        for d, v, t in zip(digests, verdicts, tokens):
+            got.insert(d, v, token=t, epoch=epoch, now=NOW)
+    else:
+        assert got.insert_batch(digests, verdicts, tokens, epoch=epoch,
+                                now=NOW) == 0
+    assert _snapshot(got) == _snapshot(ref)
+
+
+def _one_shard(cap):
+    vc = V.VerdictCache(capacity=cap, shards=1)
+    old = [V.token_digest(f"old{i}") for i in range(cap)]
+    vc.insert_batch(old, [b'{"n":%d}' % i for i in range(cap)],
+                    epoch=None)
+    return vc, old
+
+
+@pytest.mark.parametrize("k", [1, 3, 4, 6])
+def test_fill_evicts_exactly_the_k_oldest(k, monkeypatch):
+    vc, old = _one_shard(4)
+    folds = []
+    real = vc._count
+    monkeypatch.setattr(vc, "_count", lambda inc: (folds.append(inc),
+                                                    real(inc)))
+    new = [V.token_digest(f"new{i}") for i in range(k)]
+    # k - 1 payloads the native scan declines (not JSON): the counter
+    # says how many, in the batch's one fold
+    verdicts = [b"not-json"] * (k - 1) + [b'{"exp":%d}' % (time.time()
+                                                           + 60)]
+    assert vc.insert_batch(new, verdicts, epoch=None) == k
+    assert folds == [{"vcache.inserts": k, "vcache.insert_skips": 0,
+                      "vcache.evictions": k,
+                      "vcache.fill_fallbacks": k - 1}]
+    assert list(vc._shards[0]) == (old + new)[k:]
+    assert vc.stats()["vcache.evictions"] == k
+    assert vc.stats()["vcache.fill_fallbacks"] == k - 1
+
+
+def test_reinsert_keeps_its_place_and_evicts_nothing():
+    vc, old = _one_shard(4)
+    assert vc.insert_batch([old[1], old[1]], [b"x", b"y"], epoch=None) == 2
+    assert list(vc._shards[0]) == old
+    assert vc._shards[0][old[1]][0] == b"y"
+    assert vc.stats()["vcache.evictions"] == 0
+    # a present digest behind a new one in a full shard: the new one
+    # evicts the oldest first, exactly as one-at-a-time inserts do
+    new = V.token_digest("new")
+    assert vc.insert_batch([new, old[0]], [b"n", b"o"], epoch=None) == 2
+    assert list(vc._shards[0]) == [old[2], old[3], new, old[0]]
+    assert vc.stats()["vcache.evictions"] == 2
+
+
+def test_concurrent_fills_and_lookups_keep_the_books():
+    """Fills from more threads than cores, with lookups between, under
+    a short switch interval: every cached digest is distinct, so each
+    insert is still cached or was evicted — a lost update breaks it."""
+    import sys
+
+    vc = V.VerdictCache(capacity=256, shards=4)
+    exp = b'{"exp":%d}' % (time.time() + 600)
+    errors = []
+
+    def work(t):
+        try:
+            for b in range(40):
+                digs = [V.token_digest(f"s{t}-{b}-{i}") for i in range(64)]
+                assert vc.insert_batch(digs, [exp] * 64, epoch=None) == 64
+                vc.lookup_batch([None] * 64, digests=digs)
+        except BaseException as e:  # noqa: BLE001 - reported below
+            errors.append(e)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=work, args=(t,))
+                   for t in range(16)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(60)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(th.is_alive() for th in threads) and not errors
+    st = vc.stats()
+    assert st["vcache.inserts"] == 16 * 40 * 64
+    assert st["vcache.clamp_drops"] == 0
+    assert st["vcache.size"] == st["vcache.inserts"] - \
+        st["vcache.evictions"] <= 256
+
+
+def test_import_entries_evicts_the_oldest():
+    vc, old = _one_shard(4)
+    vc.set_epoch(None)
+    far = time.time() + 3600
+    rows = [[V.token_digest(f"peer{i}").hex(), "eyJzdWIiOiJ4In0=", 0.0,
+             far, None] for i in range(3)]
+    assert vc.import_entries(rows, epoch=None) == 3
+    assert list(vc._shards[0]) == old[3:] + [
+        V.token_digest(f"peer{i}") for i in range(3)]
+    st = vc.stats()
+    assert st["vcache.evictions"] == 3 and st["vcache.peer_fills"] == 3
+
+
+# ---------------------------------------------------------------------------
 # batcher: in-flight replay dedup
 # ---------------------------------------------------------------------------
 
