@@ -69,3 +69,20 @@ class Faulty:
             if last is not None:
                 return [last[i % len(last)] for i in range(n)]
         return out
+
+
+# -- keysets a test puts in the program's place, by name, so that a
+# worker process of a layout can be handed one (a lambda does not pickle)
+
+def cpu_keyset(jwks_doc: dict, cfg: dict):
+    """The program's CPU verifier over the same JWKS."""
+    from cap_tpu.jwt.jwk import parse_jwks
+    from cap_tpu.jwt.keyset import StaticKeySet
+
+    return StaticKeySet([j.key for j in parse_jwks(jwks_doc)])
+
+
+def faulty(fault: str, jwks_doc: dict, cfg: dict):
+    """``cpu_keyset`` broken by ``fault`` (bind it with
+    ``functools.partial``)."""
+    return Faulty(cpu_keyset(jwks_doc, cfg), fault)

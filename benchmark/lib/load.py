@@ -21,6 +21,7 @@ import threading
 import time
 
 from . import cvb1, traffic
+from .oracle import RELAY_HEAD
 
 clock = time.perf_counter
 
@@ -31,6 +32,8 @@ class _Recorder:
         self.done = []            # (t_start, t_done, n_tokens)
         self.first = {}           # pool index -> (status, payload)
         self.inconsistent = 0
+        self.rewrapped = 0        # of those, one reject under another's
+                                  # RELAY_HEAD
         self.errors = []
         self.sent = []            # (t_start, n_tokens) of every request
         self.lateness = []        # open loop: (scheduled, actual - scheduled)
@@ -38,16 +41,25 @@ class _Recorder:
     def answers(self, t_start, idx, out):
         t_done = clock()
         first = self.first
-        bad = 0
+        bad = rewrapped = 0
         for i, a in zip(idx, out):
             prev = first.get(i)
             if prev is None:
                 first[i] = a
             elif prev != a:
                 bad += 1
+                rewrapped += _rewrapped(prev, a)
         with self.lock:
             self.done.append((t_start, t_done, len(idx)))
             self.inconsistent += bad
+            self.rewrapped += rewrapped
+
+
+def _rewrapped(a, b) -> bool:
+    """Two answers that differ only by one reject carrying the other
+    under ``RELAY_HEAD``."""
+    return a[0] == b[0] == 1 and (a[1] == RELAY_HEAD + b[1]
+                                  or b[1] == RELAY_HEAD + a[1])
 
 
 def _connect(port: int):
@@ -158,7 +170,8 @@ def run_client(ctl, port, tokens, t: dict, seed: int, proc: int,
     with rec.lock:
         ctl.send(("result", {
             "done": rec.done, "sent": rec.sent, "first": rec.first,
-            "inconsistent": rec.inconsistent, "errors": rec.errors,
+            "inconsistent": rec.inconsistent,
+            "rewrapped": rec.rewrapped, "errors": rec.errors,
             "lateness": rec.lateness, "hung_conns": hung,
             "window": (window.get("t0"), window.get("t1"))}))
 

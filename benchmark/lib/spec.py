@@ -2,7 +2,8 @@
 configuration file it names, ``traffic/<traffic>.json``, and one reader
 ``metrics/<metric>.py`` per per-layer metric. Adding a cell, a
 configuration, a traffic mix or a metric adds files and an entry; no
-file here changes."""
+file here changes. What a configuration file may state (keys, algs,
+claims profiles, a worker layout) is in ``configs/README.md``."""
 
 from __future__ import annotations
 

@@ -7,13 +7,15 @@ verify path on the chip.
 
 The system under test is ``VerifyWorker`` on its native serve chain,
 in this process (which holds the chip), over a ``TPUBatchKeySet`` with
-no CPU fallback. Client processes that never import JAX drive it over
-CVB1. Set-up makes keys and tokens from the seed, starts the worker,
-verifies the traffic's warm-up batches straight through the keyset, and
-runs the cell's own traffic until enough of it was answered with no
-program built; the window follows without a pause. Every answer is
-compared with the plain reference (``lib/oracle.py``) once the window
-has closed.
+no CPU fallback. A configuration with a ``layout`` runs one such worker
+per chip, each in a process of its own, behind the program's front door
+(``lib/fleet.py``); this process then imports no JAX. Client processes
+that never import JAX drive it over CVB1. Set-up makes keys and tokens
+from the seed, starts the worker, verifies the traffic's warm-up
+batches straight through the keyset, and runs the cell's own traffic
+until enough of it was answered with no program built; the window
+follows without a pause. Every answer is compared with the plain
+reference (``lib/oracle.py``) once the window has closed.
 
 Earlier lines go to standard error; the last line of standard output
 is the JSON result. Without a TPU, or with fewer chips than the cell
@@ -31,151 +33,23 @@ import json  # noqa: E402
 import multiprocessing  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
-import tempfile  # noqa: E402
 
 # the benchmark's own files, and the checkout whose program it runs
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(1, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from lib import fixtures, load, oracle, spec, stats, trace  # noqa: E402
+from lib import (fixtures, fleet, load, oracle, spec, stats,  # noqa: E402
+                 sut, trace)
+from lib.sut import (BenchError, CompileCounter, TracedKeySet,  # noqa: E402
+                     build_native, prewarm_batches, require_chips, say,
+                     start_worker, tpu_keyset, use_compile_cache,
+                     window_stats)
 
 TRACE_MAX_S = 5.0       # a traced window is at most this long: the
                         # trace of a busy 10 s window took ~200 s to
                         # stop and read back (my chip run, PR 22)
 GRACE_S = 60.0          # wait for answers past the window's close
 WARM_MAX_S = 1100.0     # give up warming after this long
-
-
-class BenchError(Exception):
-    """The run cannot produce a result."""
-
-
-def say(tag: str, **fields) -> None:
-    print(f"{tag}: " + " ".join(f"{k}={v}" for k, v in fields.items()),
-          file=sys.stderr, flush=True)
-
-
-class CompileCounter:
-    """Records each program JAX traces, lowers and compiles (or loads
-    from its cache), from the duration events JAX reports for each."""
-
-    EVENTS = {"/jax/core/compile/jaxpr_trace_duration": "trace",
-              "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
-              "/jax/core/compile/backend_compile_duration": "compile"}
-
-    def __init__(self):
-        import jax
-
-        self.events = []          # (time, kind, seconds)
-        jax.monitoring.register_event_duration_secs_listener(self._on)
-
-    def _on(self, event, duration, **_):
-        kind = self.EVENTS.get(event)
-        if kind is not None:
-            self.events.append((time.perf_counter(), kind, duration))
-
-    def between(self, t0, t1, kind="compile"):
-        return [d for t, k, d in self.events if t0 <= t < t1 and k == kind]
-
-    def last(self) -> float:
-        return self.events[-1][0] if self.events else 0.0
-
-    def per_second(self, t0: float):
-        """[[second since t0, events, seconds they took], ...]."""
-        bins = {}
-        for t, _, d in self.events:
-            b = bins.setdefault(int(t - t0), [0, 0.0])
-            b[0] += 1
-            b[1] += d
-        return [[k, n, round(d, 2)] for k, (n, d) in sorted(bins.items())]
-
-
-def require_chips(n: int):
-    import jax
-
-    devs = jax.devices()
-    if devs[0].platform != "tpu":
-        raise BenchError(f"no TPU: JAX reports platform "
-                         f"{devs[0].platform!r}")
-    if len(devs) < n:
-        raise BenchError(f"the cell needs {n} chips, JAX reports "
-                         f"{len(devs)}")
-    return devs[:n]
-
-
-def use_compile_cache() -> str:
-    """JAX's persistent cache at a fixed path inside the checkout; a
-    program that reads JAX_COMPILATION_CACHE_DIR takes the same one."""
-    import jax
-
-    path = os.path.join(spec.ROOT, ".jax_cache")
-    os.makedirs(path, exist_ok=True)
-    os.environ["JAX_COMPILATION_CACHE_DIR"] = path
-    jax.config.update("jax_compilation_cache_dir", path)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    return path
-
-
-class TracedKeySet:
-    """The keyset as the worker sees it, with the benchmark's own
-    profiler spans around each call into it (traced runs only)."""
-
-    def __init__(self, inner):
-        self._inner = inner
-
-    def __getattr__(self, name):
-        attr = getattr(self._inner, name)
-        if name != "verify_batch_async_raw":
-            return attr
-        from jax.profiler import TraceAnnotation
-
-        def traced_dispatch(tokens):
-            with TraceAnnotation("bench.keyset.dispatch"):
-                collect = attr(tokens)
-
-            def traced_collect():
-                with TraceAnnotation("bench.keyset.collect"):
-                    return collect()
-
-            return traced_collect
-
-        return traced_dispatch
-
-
-def tpu_keyset(jwks_doc: dict, cfg: dict):
-    from cap_tpu.jwt.jwk import parse_jwks
-    from cap_tpu.jwt.tpu_keyset import TPUBatchKeySet
-
-    return TPUBatchKeySet(parse_jwks(jwks_doc), cpu_fallback=False,
-                          max_chunk=cfg["keyset"]["max_chunk"])
-
-
-def start_worker(keyset, cfg: dict):
-    from cap_tpu import telemetry
-    from cap_tpu.serve.worker import VerifyWorker
-
-    # As a deployed worker does (cap_tpu/fleet/worker_main.py): the
-    # recorder is on, so STATS and the per-layer readers see counters.
-    telemetry.enable()
-    w = cfg["worker"]
-    worker = VerifyWorker(
-        keyset, target_batch=w["target_batch"], max_wait_ms=w["max_wait_ms"],
-        max_batch=w["max_batch"], serve_native=w["serve_native"],
-        vcache=w["vcache"], vcache_capacity=w["vcache_capacity"],
-        fair=w["fair"], transport=w["transport"])
-    if w["serve_native"] and worker.serve_chain != "native":
-        worker.close()
-        raise BenchError(f"serve chain is {worker.serve_chain}, not native")
-    return worker
-
-
-def build_native() -> float:
-    from cap_tpu import _build
-
-    t0 = time.perf_counter()
-    _build.build_native()
-    return time.perf_counter() - t0
 
 
 class Clients:
@@ -227,27 +101,7 @@ class Clients:
                 proc.join(5)
 
 
-def prewarm(keyset, tokens, cfg: dict, sizes: dict) -> None:
-    """Verify, straight through the keyset, one batch of pool tokens of
-    each algorithm at each size in ``sizes`` ({alg: [n, ...]}), all
-    dispatched before any is collected. The keyset sizes its chunks from
-    the link rate it measures, so the traffic alone meets some batch
-    shapes only after minutes; these batches build each one now."""
-    alg_of = {k["kid"]: k["alg"] for k in cfg["keys"]}
-    order = cfg["kid_order"]
-    by_alg = {}
-    for j, tok in enumerate(tokens):
-        by_alg.setdefault(alg_of[order[j % len(order)]], []).append(tok)
-    batches = [by_alg[alg][:n] for alg, ns in sizes.items() for n in ns]
-    if not hasattr(keyset, "verify_batch_async_raw"):
-        for b in batches:
-            keyset.verify_batch(b)
-        return
-    for collect in [keyset.verify_batch_async_raw(b) for b in batches]:
-        collect()
-
-
-def warm_up(compiles: CompileCounter, clients, t: dict):
+def warm_up(compiles, clients, t: dict):
     """Run the cell's own traffic until ``warm_tokens`` tokens were
     answered in requests sent after the last program was built. (A new
     shape shows as a trace event only once its tracing is done, so only
@@ -262,12 +116,6 @@ def warm_up(compiles: CompileCounter, clients, t: dict):
         if clients.tokens_answered_since(last) >= t["warm_tokens"] \
                 and compiles.last() == last:
             return
-
-
-def window_stats(worker) -> dict:
-    s = worker.stats()
-    return {"counters": dict(s.get("counters") or {}),
-            "series": dict((s.get("snapshot") or {}).get("series") or {})}
 
 
 def layer_ctx(before: dict, after: dict, tsum: dict, window_s: float):
@@ -334,28 +182,88 @@ def measure(args, keyset_factory=None, need_chip=True,
                    else args.seconds)
         d = s.drive(c["traffic"], seconds, args.trace)
         setup_s = d["w0"] - T_START
-        peak = max((dev.memory_stats() or {}).get("peak_bytes_in_use", 0)
-                   for dev in s.devs)
-        s.close_worker()
+        peak = s.sut.memory_peak()
+        device = s.sut.device()
+        s.sut.close()
         say("setup", **{k: round(v, 3) if isinstance(v, float) else v
                         for k, v in s.setup.items()},
             setup_s=round(setup_s, 3))
         t0 = time.perf_counter()
         cmp = compare(d["results"], s.jwks_doc, s.tokens, s.pool)
         ref_s = time.perf_counter() - t0
-        return report(args, c, s.devs, setup_s, d, cmp, ref_s, peak,
+        return report(args, c, device, setup_s, d, cmp, ref_s, peak,
                       len(s.tampered))
     finally:
         s.close()
 
 
+class InProcess:
+    """The worker in this process, which holds the chip: the path of a
+    configuration without ``layout``."""
+
+    def __init__(self, c: dict, traced: bool, keyset_factory=None,
+                 need_chip: bool = True):
+        import jax
+
+        self.cfg, self.traced, self.factory = c["config"], traced, \
+            keyset_factory
+        self.worker = None
+        self.devs = (require_chips(c["workload"]["chips"]) if need_chip
+                     else jax.devices()[:1])
+        say("device", platform=self.devs[0].platform,
+            kind=json.dumps(self.devs[0].device_kind), count=len(self.devs),
+            jax=jax.__version__,
+            cache=use_compile_cache() if need_chip else "off")
+        self.compiles = CompileCounter()
+
+    def start(self, jwks_doc: dict) -> None:
+        self.keyset = (self.factory or tpu_keyset)(jwks_doc, self.cfg)
+        self.worker = start_worker(
+            TracedKeySet(self.keyset) if self.traced else self.keyset,
+            self.cfg)
+        self.port = self.worker.address[1]
+
+    def prewarm(self, batches) -> None:
+        sut.run_prewarm(self.keyset, batches)
+
+    def trace_start(self) -> None:
+        self.tdir = sut.start_profile()
+
+    def window(self, w0: float, w1: float):
+        """The worker's stats at both ends of the window, which is a
+        host span of the trace."""
+        import jax
+
+        time.sleep(max(0.0, w0 - time.perf_counter()))
+        before = window_stats(self.worker)
+        with jax.profiler.TraceAnnotation(trace.WINDOW):
+            time.sleep(max(0.0, w1 - time.perf_counter()))
+        return before, window_stats(self.worker)
+
+    def trace_stop(self) -> dict:
+        return sut.stop_profile(self.tdir)
+
+    def memory_peak(self) -> int:
+        return sut.memory_peak(self.devs)
+
+    def device(self) -> dict:
+        return {"platform": self.devs[0].platform,
+                "kind": self.devs[0].device_kind, "count": len(self.devs)}
+
+    def close(self) -> None:
+        if self.worker is not None:
+            self.worker.close()
+            self.worker = None
+
+
 class Session:
     """One run's set-up: keys and tokens from the seed (signed in a
-    process pool), the worker on the chip; then ``drive`` runs traffic
-    against it."""
+    process pool), the system under test (``InProcess``, or a
+    ``fleet.Fleet`` where the configuration has a ``layout``); then
+    ``drive`` runs traffic against it."""
 
     def __init__(self, args, c, keyset_factory=None, need_chip=True):
-        self.args, self.c, self.worker = args, c, None
+        self.args, self.c, self.sut = args, c, None
         self.ctx = multiprocessing.get_context("spawn")
         self.pool = self.ctx.Pool(max(1, min(12, (os.cpu_count() or 2) - 1)))
         try:
@@ -366,22 +274,15 @@ class Session:
 
     def _start(self, keyset_factory, need_chip):
         args, cfg, t = self.args, self.c["config"], self.c["traffic"]
-        rsa_kids = [k["kid"] for k in cfg["keys"] if k["kty"] == "RSA"]
-        keys_async = self.pool.starmap_async(
-            fixtures.rsa_numbers, [(args.seed, kid) for kid in rsa_kids])
-        import jax
-
-        self.devs = (require_chips(self.c["workload"]["chips"]) if need_chip
-                     else jax.devices()[:1])
-        say("device", platform=self.devs[0].platform,
-            kind=json.dumps(self.devs[0].device_kind), count=len(self.devs),
-            jax=jax.__version__,
-            cache=use_compile_cache() if need_chip else "off")
-        self.compiles = CompileCounter()
+        jobs = fixtures.rsa_jobs(cfg["keys"], args.seed)
+        keys_async = self.pool.starmap_async(fixtures.rsa_numbers, jobs)
+        kind = fleet.Fleet if "layout" in cfg else InProcess
+        self.sut = kind(self.c, args.trace, keyset_factory, need_chip)
         self.setup = {"build_s": build_native()}
         t0 = time.perf_counter()
-        specs = fixtures.key_specs(cfg["keys"], args.seed,
-                                   dict(zip(rsa_kids, keys_async.get())))
+        specs = fixtures.key_specs(
+            cfg["keys"], args.seed,
+            dict(zip([j[1] for j in jobs], keys_async.get())))
         self.jwks_doc = fixtures.jwks_document(specs)
         self.setup["keys_s"] = time.perf_counter() - t0
         t_sign = time.perf_counter()
@@ -390,9 +291,7 @@ class Session:
             (specs, cfg["kid_order"], cfg["claims"], args.seed, lo,
              min(n, lo + 2048)) for lo in range(0, n, 2048)])
         t0 = time.perf_counter()
-        self.keyset = (keyset_factory or tpu_keyset)(self.jwks_doc, cfg)
-        self.worker = start_worker(
-            TracedKeySet(self.keyset) if args.trace else self.keyset, cfg)
+        self.sut.start(self.jwks_doc)
         self.setup["worker_s"] = time.perf_counter() - t0
         tokens = [tok for part in sign_async.get() for tok in part]
         self.tokens, self.tampered = fixtures.with_tampered(
@@ -403,74 +302,50 @@ class Session:
         """Run traffic ``t``: warm up until nothing compiles, then the
         window. Returns the clients' records and the worker's counters
         and trace around the window."""
-        import jax
-
+        compiles = self.sut.compiles
         t0 = time.perf_counter()
-        prewarm(self.keyset, self.tokens, self.c["config"],
-                t.get("warm_sizes", {}))
+        self.sut.prewarm(prewarm_batches(self.tokens, self.c["config"],
+                                         t.get("warm_sizes", {})))
         self.setup["prewarm_s"] = time.perf_counter() - t0
         t0 = time.perf_counter()
-        clients = Clients(self.ctx, self.worker.address[1], self.tokens, t,
+        clients = Clients(self.ctx, self.sut.port, self.tokens, t,
                           self.args.seed)
         self.setup["clients_s"] = time.perf_counter() - t0
         try:
             t0 = time.perf_counter()
-            warm_up(self.compiles, clients, t)
+            warm_up(compiles, clients, t)
             self.setup["warm_s"] = time.perf_counter() - t0
             for kind in ("trace", "lower", "compile"):
-                built = self.compiles.between(0, time.perf_counter(), kind)
+                built = compiles.between(0, time.perf_counter(), kind)
                 self.setup[f"{kind}s"] = len(built)
                 self.setup[f"{kind}_s"] = sum(built)
-            say("built", last_s=round(self.compiles.last() - T_START, 1),
-                seconds=json.dumps(self.compiles.per_second(T_START)))
+            say("built", last_s=round(compiles.last() - T_START, 1),
+                seconds=json.dumps(compiles.per_second(T_START)))
             if traced:
-                tdir = tempfile.TemporaryDirectory(prefix="bench-trace-")
-                opts = jax.profiler.ProfileOptions()
-                opts.python_tracer_level = 0
-                # annotations only (the benchmark's own spans and
-                # JAX's level-1 events): a full host trace of a busy
-                # window takes minutes to read back
-                opts.host_tracer_level = 1
-                jax.profiler.start_trace(tdir.name, profiler_options=opts)
+                self.sut.trace_start()
             w0 = time.perf_counter() + 0.05
             w1 = w0 + seconds
             clients.window(w0, w1)
-            time.sleep(max(0.0, w0 - time.perf_counter()))
-            before = window_stats(self.worker)
-            with jax.profiler.TraceAnnotation(trace.WINDOW):
-                time.sleep(max(0.0, w1 - time.perf_counter()))
-            after = window_stats(self.worker)
-            tsum = {}
-            if traced:
-                t_stop = time.perf_counter()
-                jax.profiler.stop_trace()
-                t_read = time.perf_counter()
-                tr = trace.load(tdir.name)
-                say("trace_cost", stop_s=round(t_read - t_stop, 3),
-                    read_s=round(time.perf_counter() - t_read, 3))
-                tdir.cleanup()
-                tsum = trace.summarize(tr)
+            before, after = self.sut.window(w0, w1)
+            tsum = self.sut.trace_stop() if traced else {}
             results = clients.results()
         finally:
             clients.close()
         return {"w0": w0, "w1": w1, "before": before, "after": after,
-                "in_window": self.compiles.between(w0, w1),
-                "traced_in_window": self.compiles.between(w0, w1, "trace"),
+                "in_window": compiles.between(w0, w1),
+                "traced_in_window": compiles.between(w0, w1, "trace"),
                 "tsum": tsum,
                 "results": results}
 
-    def close_worker(self):
-        if self.worker is not None:
-            self.worker.close()
-            self.worker = None
-
     def close(self):
-        self.close_worker()
+        if self.sut is not None:
+            self.sut.close()
         self.pool.terminate()
         self.pool.join()
 
 
-def report(args, c, devs, setup_s, d, cmp, ref_s, peak, n_tampered) -> dict:
+def report(args, c, device, setup_s, d, cmp, ref_s, peak,
+           n_tampered) -> dict:
     t = c["traffic"]
     w0, w1, results, tsum = d["w0"], d["w1"], d["results"], d["tsum"]
     in_window = d["in_window"]
@@ -482,6 +357,7 @@ def report(args, c, devs, setup_s, d, cmp, ref_s, peak, n_tampered) -> dict:
     errors = [e for r in results for e in r["errors"]]
     hung = sum(r["hung_conns"] for r in results)
     inconsistent = sum(r["inconsistent"] for r in results)
+    rewrapped = sum(r["rewrapped"] for r in results)
     window_s = w1 - w0
     lat = [(d[1] - d[0]) * 1e3 for d in done]
     late = [x * 1e3 for r in results for t0, x in r["lateness"]
@@ -493,6 +369,11 @@ def report(args, c, devs, setup_s, d, cmp, ref_s, peak, n_tampered) -> dict:
         generator_late_ms_p50=stats.percentile(late, 50),
         generator_late_ms_p99=stats.percentile(late, 99),
         generator_late_ms_max=max(late) if late else None)
+    built = {k[len("compile.fn."):]: v - d["before"]["counters"].get(k, 0)
+             for k, v in d["after"]["counters"].items()
+             if k.startswith("compile.fn.")}
+    say("built_in_window",
+        programs=json.dumps({k: v for k, v in built.items() if v}))
     if t.get("latency") and len(lat) < 1000:
         say("warning", latency_samples=len(lat),
             note="fewer_than_1000_so_p99_has_under_10_beyond_it")
@@ -530,19 +411,24 @@ def report(args, c, devs, setup_s, d, cmp, ref_s, peak, n_tampered) -> dict:
     say("compare", checked_tokens=cmp["checked_tokens"],
         accepted_by_reference=cmp["accepted_ref"],
         tampered_in_pool=n_tampered, reference_s=round(ref_s, 3),
+        rewrapped_rejects=rewrapped,
         examples=json.dumps(cmp["examples"] + errors[:3]))
     for k, (v, lim) in checks.items():
         print(f"check {k}={v} limit={lim}", file=sys.stderr, flush=True)
     correct = all(v <= lim for v, lim in checks.values())
-    device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
-              "count": len(devs), "memory_peak_bytes": int(peak)}
+    device = dict(device, memory_peak_bytes=int(peak))
     if args.trace and tsum:
-        device["busy_s"] = tsum["busy_s"]
-        device["window_s"] = tsum["window_s"]
+        # per chip: a fleet's trace sums its workers' seconds
+        n = tsum.get("workers", 1)
+        device["busy_s"] = tsum["busy_s"] / n
+        device["window_s"] = tsum["window_s"] / n
     out = {"correct": correct, "attempted": len(sent), "failed": unanswered,
            "metrics": metrics, "device": device}
     if breakdown is not None:
         out["breakdown"] = breakdown
+    # repeats that differ only by a router's re-raised reject (counted
+    # among inconsistent_repeats; see lib/load.py)
+    out["diagnostics"] = {"rewrapped_rejects": rewrapped}
     out["checks"] = checks
     return out
 

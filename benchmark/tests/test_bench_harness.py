@@ -105,7 +105,7 @@ def test_zipf_pool_hit_ratio_model():
 
 
 def test_prewarm_dispatches_every_size_before_collecting():
-    import run
+    from lib import sut
 
     log = []
 
@@ -119,7 +119,8 @@ def test_prewarm_dispatches_every_size_before_collecting():
                     {"kid": "es-0", "alg": "ES256"}],
            "kid_order": ["rs-0", "es-0"]}
     toks = [f"{'rs' if j % 2 == 0 else 'es'}-{j}" for j in range(64)]
-    run.prewarm(Async(), toks, cfg, {"RS256": [8, 16], "ES256": [32]})
+    sut.run_prewarm(Async(), sut.prewarm_batches(
+        toks, cfg, {"RS256": [8, 16], "ES256": [32]}))
     assert log[:3] == [("dispatch", {"rs"}, 8), ("dispatch", {"rs"}, 16),
                        ("dispatch", {"es"}, 32)]
     assert log[3:] == [("collect",)] * 3
