@@ -36,7 +36,8 @@ Counters (exact: ``frontdoor.lookups == frontdoor.affinity_hits +
 frontdoor.affinity_misses``, obs-smoke gates it; misses further split
 into spills + reroutes + rr-routed):
 ``frontdoor.lookups / affinity_hits / affinity_misses / spills /
-reroutes / fallback_tokens / keys_pushes``.
+reroutes / fallback_tokens / keys_pushes``, and
+``frontdoor.pool.<id>.tokens`` (tokens sent to each pool).
 """
 
 from __future__ import annotations
@@ -52,7 +53,8 @@ from .. import telemetry
 from ..obs import decision as _decision
 from ..serve import protocol
 from ..serve import vcache as _vcache
-from .router import FleetClient, FleetExhaustedError
+from .router import (FleetClient, FleetExhaustedError, accounting_view,
+                     decode_verdict)
 
 Endpoint = Tuple[str, int]
 
@@ -180,6 +182,9 @@ class FrontDoor:
                      "frontdoor.reroutes": 0,
                      "frontdoor.fallback_tokens": 0,
                      "frontdoor.keys_pushes": 0}
+        # present from the start, so an idle pool reads 0, not absent
+        for a in self._arms:
+            self._ctr[f"frontdoor.pool.{a.pool_id}.tokens"] = 0
 
     # -- routing ----------------------------------------------------------
 
@@ -190,6 +195,24 @@ class FrontDoor:
         preserved, whatever pool (or the terminal fallback) produced
         each verdict. ``digests``: optional per-token sha256[:16]
         (reader-computed upstream); missing ones are hashed here."""
+        return [decode_verdict(v) for v in self._route(tokens, digests)]
+
+    def verify_batch_wire(self, tokens: Sequence[str],
+                         digests: Optional[Sequence[Optional[bytes]]]
+                         = None) -> List[protocol.RawVerdict]:
+        """The same routing, answered with each pool worker's own wire
+        entry per token (``protocol.RawVerdict``), untouched — so a
+        token's answer has the same bytes whichever route it took. The
+        terminal fallback's verdicts are encoded as a worker encodes
+        them."""
+        return [protocol.raw_verdict(v)
+                for v in self._route(tokens, digests)]
+
+    def _route(self, tokens: Sequence[str],
+               digests: Optional[Sequence[Optional[bytes]]]
+               ) -> List[Any]:
+        """Each token's answer: its pool worker's wire entry, or the
+        terminal fallback's own verdict."""
         tokens = list(tokens)
         if not tokens:
             return []
@@ -213,13 +236,14 @@ class FrontDoor:
                     threads.append(th)
                 for th in threads:
                     th.join()
-        _decision.record_batch("frontdoor", out, tokens=tokens,
+        seen = [accounting_view(v) for v in out]
+        _decision.record_batch("frontdoor", seen, tokens=tokens,
                                latency_s=time.perf_counter() - t0)
         # Admission pushback accounting (r20): throttled rejects are
         # TERMINAL here exactly as at the router — the front door
         # never re-routes or oracle-falls-back a shed token (that
         # would defeat admission); it only counts what came back.
-        thr = sum(1 for r in out
+        thr = sum(1 for r in seen
                   if isinstance(r, Exception)
                   and _decision.classify(r)
                   == _decision.REASON_THROTTLED)
@@ -360,8 +384,11 @@ class FrontDoor:
                         idxs: List[int], out: List[Any],
                         hits0: int = 0) -> None:
         """One arm's sub-batch: primary arm → ring re-route chain →
-        terminal fallback. Writes verdicts into ``out`` in place
-        (disjoint index sets per group — no lock needed)."""
+        terminal fallback. Writes the answers (a worker's wire entries,
+        or the fallback's verdicts) into ``out`` in place (disjoint
+        index sets per group — no lock needed);
+        ``frontdoor.pool.<id>.tokens`` counts what each pool was
+        sent."""
         sub = [tokens[i] for i in idxs]
         tried = set()
         chain = [arm_id] + [a.pool_id for a in self._arms
@@ -387,8 +414,9 @@ class FrontDoor:
                     arm.reroutes_in += len(sub)
             with self._lock:
                 arm.inflight += len(sub)
+            self._count({f"frontdoor.pool.{pid}.tokens": len(sub)})
             try:
-                results = arm.client.verify_batch(sub)
+                results = arm.client.verify_batch_wire(sub)
                 break
             except (FleetExhaustedError, OSError,
                     protocol.ProtocolError):
@@ -639,6 +667,12 @@ _FDC_NAMES = ("conns", "frames", "tokens", "proto_errors", "pongs",
               "seq_held_max", "dropped_posts", "conns_closed")
 
 
+_VERIFY_FRAMES = (protocol.T_VERIFY_REQ, protocol.T_VERIFY_REQ_CRC,
+                  protocol.T_VERIFY_REQ_TRACE)
+# slow-path verify frames answered at once: each waits on its pools
+_SLOW_THREADS = 8
+
+
 class NativeFrontDoorServer:
     """The zero-copy relay front door: C++ per-connection readers
     parse/validate/classify each CVB1 frame ONCE at the edge, look the
@@ -650,7 +684,11 @@ class NativeFrontDoorServer:
     drain through ``cap_frontdoor_drain`` and are answered via
     ``cap_frontdoor_post_raw`` — the twin pattern (drr.py) keeps the
     routing decision itself pinned bit-exact via
-    ``cap_frontdoor_probe_route``.
+    ``cap_frontdoor_probe_route``. The drain thread answers control
+    frames itself and hands each slow-path verify frame to one of
+    ``_SLOW_THREADS`` threads, so a STATS or KEYS frame never waits
+    behind verify frames, nor one verify frame behind another (the
+    native side puts responses back in each connection's seq order).
 
     Surface-compatible with ``VerifyWorker(FrontDoor(...))`` — the
     deployable gateway worker_main builds for ``--frontdoor-chain
@@ -665,10 +703,13 @@ class NativeFrontDoorServer:
     ``frontdoor.lookups == affinity_hits + affinity_misses`` invariant
     survives the split (obs-smoke gates it through this chain).
 
-    Known undercount: per-POOL ``tokens`` / ``affinity_hits`` arm
-    attribution only sees slow-path traffic (the native relay keeps
-    per-pool in-flight gauges, not lifetime arm counters) — the
-    fleet-level counters above are exact either way.
+    Byte identity: a slow-path verify frame is answered with each
+    worker's own wire entries (``FrontDoor.verify_batch_wire``), so a
+    token's answer has the same bytes on either path
+    (``frontdoor.slow.raw_entries`` counts them). Per-pool relayed
+    tokens, ``frontdoor.pool.<id>.tokens``, fold the native slot of
+    each pool into the slow path's own count. The snapshot's per-arm
+    ``tokens`` / ``affinity_hits`` still see slow-path traffic only.
     """
 
     def __init__(self, frontdoor: FrontDoor, host: str = "127.0.0.1",
@@ -676,6 +717,7 @@ class NativeFrontDoorServer:
                  drain_wait_s: float = 0.1, refresh_s: float = 0.25,
                  max_frames: int = 64):
         import ctypes
+        import queue
         import socket as _socket
 
         import numpy as np
@@ -696,6 +738,7 @@ class NativeFrontDoorServer:
                 f"native relay supports at most {_ns.FD_MAX_POOLS} "
                 f"pools, got {len(frontdoor._arms)}")
         self._fd = frontdoor
+        frontdoor._ctr.setdefault("frontdoor.slow.raw_entries", 0)
         self._ns = _ns
         self._np = np
         self._lib = lib
@@ -710,8 +753,7 @@ class NativeFrontDoorServer:
         self._closed = False
         self._stop_ev = threading.Event()
         self._ctr_lock = threading.Lock()
-        self._last_lookups = 0
-        self._last_hits = 0
+        self._folded: Dict[str, int] = {}     # native totals folded so far
         self._ep_sig: Optional[tuple] = None
         self._h = ctypes.c_void_p(lib.cap_frontdoor_create())
         try:
@@ -736,10 +778,13 @@ class NativeFrontDoorServer:
             self._obs = ObsServer(host=host, port=obs_port,
                                   extra=self._obs_gauges,
                                   snapshot_extra=self._obs_snapshot)
+        self._slow_q: "queue.SimpleQueue" = queue.SimpleQueue()
         self._threads = []
-        for name, fn in (("cap-tpu-fd-accept", self._accept_loop),
-                         ("cap-tpu-fd-drain", self._drain_loop),
-                         ("cap-tpu-fd-refresh", self._refresh_loop)):
+        loops = [("cap-tpu-fd-accept", self._accept_loop),
+                 ("cap-tpu-fd-drain", self._drain_loop),
+                 ("cap-tpu-fd-refresh", self._refresh_loop)]
+        loops += [("cap-tpu-fd-slow", self._slow_loop)] * _SLOW_THREADS
+        for name, fn in loops:
             th = threading.Thread(target=fn, daemon=True, name=name)
             th.start()
             self._threads.append(th)
@@ -839,7 +884,8 @@ class NativeFrontDoorServer:
             pass
         # The drain thread must be OUT of cap_frontdoor_drain before
         # the handle dies (destroy frees it); it exits on its next
-        # empty poll once _closed is set.
+        # empty poll once _closed is set, and then stops the slow
+        # threads once they have posted what they hold.
         deadline = time.monotonic() + max(1.0, deadline_s)
         for th in self._threads:
             th.join(timeout=max(0.1, deadline - time.monotonic()))
@@ -909,19 +955,23 @@ class NativeFrontDoorServer:
                 "gauges": {}, "series": {}}
 
     def _fold_native_counters(self) -> None:
-        """Fold native fast-path lookup/hit deltas into the wrapped
-        front door's exact counters (relays go only to live primaries:
-        the two deltas are equal, misses stay 0 for native traffic)."""
+        """Fold native fast-path deltas into the wrapped front door's
+        exact counters: lookups and hits (relays go only to live
+        primaries: the two deltas are equal, misses stay 0 for native
+        traffic), and each pool's relayed tokens, which the slow path
+        counts under the same ``frontdoor.pool.<id>.tokens``."""
         lib, h, ns = self._lib, self._h, self._ns
         with self._ctr_lock:
-            cur_l = int(lib.cap_frontdoor_counter(h, ns.FDC_LOOKUPS))
-            cur_h = int(lib.cap_frontdoor_counter(h, ns.FDC_HITS))
-            d_l, d_h = cur_l - self._last_lookups, \
-                cur_h - self._last_hits
-            self._last_lookups, self._last_hits = cur_l, cur_h
-        if d_l or d_h:
-            self._fd._count({"frontdoor.lookups": d_l,
-                             "frontdoor.affinity_hits": d_h})
+            cur = {"frontdoor.lookups":
+                   int(lib.cap_frontdoor_counter(h, ns.FDC_LOOKUPS)),
+                   "frontdoor.affinity_hits":
+                   int(lib.cap_frontdoor_counter(h, ns.FDC_HITS))}
+            for arm in self._fd._arms:
+                cur[f"frontdoor.pool.{arm.pool_id}.tokens"] = int(
+                    lib.cap_frontdoor_pool_tokens(h, arm.pool_id))
+            inc = {k: v - self._folded.get(k, 0) for k, v in cur.items()}
+            self._folded = cur
+        self._fd._count(inc)
 
     # -- threads ----------------------------------------------------------
 
@@ -961,6 +1011,13 @@ class NativeFrontDoorServer:
                 pass
 
     def _drain_loop(self) -> None:
+        try:
+            self._drain()
+        finally:
+            for _ in range(_SLOW_THREADS):
+                self._slow_q.put(None)
+
+    def _drain(self) -> None:
         np, lib, ct = self._np, self._lib, self._ct
         mf = self._max_frames
         blob = np.zeros(1 << 20, np.uint8)
@@ -992,18 +1049,33 @@ class NativeFrontDoorServer:
                 rname = _SLOW_REASONS.get(reason, f"r{reason}")
                 self._fd._count(
                     {f"frontdoor.native.slow.{rname}": 1})
-                try:
-                    resp = self._handle_slow(raw, ftype, ntok)
-                except Exception as e:  # noqa: BLE001 - must answer
-                    resp = protocol.encode_response(
-                        [e] * max(1, ntok),
-                        crc=ftype == protocol.T_VERIFY_REQ_CRC)
-                rb = np.frombuffer(resp, np.uint8)
-                lib.cap_frontdoor_post_raw(
-                    self._h, conn_id, int(seqs[k]),
-                    rb.ctypes.data_as(self._u8p), len(resp))
+                job = (raw, ftype, ntok, conn_id, int(seqs[k]))
+                if ftype in _VERIFY_FRAMES:
+                    self._slow_q.put(job)
+                else:
+                    self._answer_slow(*job)
             if n == 0 and self._closed:
                 return
+
+    def _slow_loop(self) -> None:
+        while True:
+            job = self._slow_q.get()
+            if job is None:
+                return
+            self._answer_slow(*job)
+
+    def _answer_slow(self, raw: bytes, ftype: int, ntok: int,
+                     conn_id: int, seq: int) -> None:
+        try:
+            resp = self._handle_slow(raw, ftype, ntok)
+        except Exception as e:  # noqa: BLE001 - must answer
+            resp = protocol.encode_response(
+                [e] * max(1, ntok),
+                crc=ftype == protocol.T_VERIFY_REQ_CRC)
+        rb = self._np.frombuffer(resp, self._np.uint8)
+        self._lib.cap_frontdoor_post_raw(
+            self._h, conn_id, seq, rb.ctypes.data_as(self._u8p),
+            len(resp))
 
     # -- the slow path ----------------------------------------------------
 
@@ -1014,12 +1086,15 @@ class NativeFrontDoorServer:
         import json as _json
 
         P = protocol
-        if ftype in (P.T_VERIFY_REQ, P.T_VERIFY_REQ_CRC,
-                     P.T_VERIFY_REQ_TRACE):
+        if ftype in _VERIFY_FRAMES:
             _ft, tokens, trace, _c = P.parse_frame_bytes(raw)
             try:
                 with telemetry.span(telemetry.SPAN_FRONTDOOR_RELAY):
-                    results = self._fd.verify_batch(tokens)
+                    # the workers' own entries, so the answer has the
+                    # bytes the native splice would have relayed
+                    results = self._fd.verify_batch_wire(tokens)
+                self._fd._count(
+                    {"frontdoor.slow.raw_entries": len(results)})
             except Exception as e:  # noqa: BLE001 - per-token errors
                 results = [e] * len(tokens)
             return P.encode_response(

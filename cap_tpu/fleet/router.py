@@ -37,6 +37,7 @@ Mechanics, in the order a batch experiences them:
 
 from __future__ import annotations
 
+import json
 import os
 import socket
 import threading
@@ -56,6 +57,30 @@ Endpoint = Tuple[str, int]
 class FleetExhaustedError(CapError):
     default_message = ("no fleet worker reachable and no fallback "
                       "keyset configured")
+
+
+def decode_verdict(v: Any) -> Any:
+    """A worker's wire entry as ``verify_batch`` returns it: the claims
+    dict, or a ``RemoteVerifyError`` carrying the worker's error
+    string. A local verdict (the terminal fallback's) passes as it
+    is."""
+    if not isinstance(v, protocol.RawVerdict):
+        return v
+    if v.status == 0:
+        return json.loads(v.payload.decode())
+    return RemoteVerifyError(v.payload.decode())
+
+
+def accounting_view(v: Any) -> Any:
+    """What the decision fold and the pushback check need of a wire
+    entry, without decoding an accept: its payload bytes, or the
+    reject as a ``RemoteVerifyError`` whose head names the worker's
+    error class. A local verdict passes as it is."""
+    if not isinstance(v, protocol.RawVerdict):
+        return v
+    if v.status == 0:
+        return v.payload
+    return RemoteVerifyError(v.payload.decode("utf-8", "replace"))
 
 
 class _Breaker:
@@ -98,15 +123,8 @@ class _Attempt:
         if len(entries) != len(tokens):
             raise protocol.ProtocolError(
                 f"response count {len(entries)} != request {len(tokens)}")
-        out: List[Any] = []
-        import json
-
-        for status, payload in entries:
-            if status == 0:
-                out.append(json.loads(payload.decode()))
-            else:
-                out.append(RemoteVerifyError(payload.decode()))
-        return out
+        return [protocol.RawVerdict(status, payload)
+                for status, payload in entries]
 
     def close(self) -> None:
         try:
@@ -323,30 +341,53 @@ class FleetClient:
         with telemetry.span(telemetry.SPAN_CLIENT_SUBMIT):
             consult = self._cache_consult(tokens)
             if consult is None:
-                out = self._verify_batch_routed(
-                    tokens, telemetry.current_trace())
+                out = [decode_verdict(v) for v in
+                       self._verify_batch_routed(
+                           tokens, telemetry.current_trace())]
             else:
                 hits, miss_idx, fill = consult
                 if miss_idx:
                     fresh = self._verify_batch_routed(
                         [tokens[i] for i in miss_idx],
                         telemetry.current_trace())
-                    out = fill(fresh)
+                    out = fill([decode_verdict(v) for v in fresh])
                 else:
                     out = hits
+        self._account(tokens, out, t0)
+        return out
+
+    def verify_batch_wire(self, tokens: Sequence[str]
+                         ) -> List[protocol.RawVerdict]:
+        """The same routing, answered with each worker's own wire
+        entries, undecoded: what a relay writes back so that a token's
+        answer has the worker's bytes. The terminal fallback's verdicts
+        come encoded as a worker encodes them. The client-side verdict
+        cache is not consulted (it holds decoded verdicts)."""
+        tokens = list(tokens)
+        if not tokens:
+            return []
+        t0 = time.perf_counter()
+        with telemetry.span(telemetry.SPAN_CLIENT_SUBMIT):
+            out = self._verify_batch_routed(
+                tokens, telemetry.current_trace())
+        self._account(tokens, [accounting_view(v) for v in out], t0)
+        return [protocol.raw_verdict(v) for v in out]
+
+    def _account(self, tokens: List[str], seen: List[Any],
+                 t0: float) -> None:
         # Router-surface decision records: the verdicts the CALLER
         # sees, whichever path produced them (worker, hedge peer, or
         # the terminal oracle) — worker rejections arrive as
         # RemoteVerifyError and classify back to the engine's reason.
-        _decision.record_batch("router", out, tokens=tokens,
+        _decision.record_batch("router", seen, tokens=tokens,
                                latency_s=time.perf_counter() - t0)
-        self._note_pushback(out)
-        return out
+        self._note_pushback(seen)
 
     # -- admission pushback ------------------------------------------------
 
     @staticmethod
     def _is_throttled(res: Any) -> bool:
+        res = accounting_view(res)
         return (isinstance(res, Exception)
                 and _decision.classify(res)
                 == _decision.REASON_THROTTLED)
@@ -392,6 +433,8 @@ class FleetClient:
 
     def _verify_batch_routed(self, tokens: List[str],
                              trace: Optional[str]) -> List[Any]:
+        """The answering worker's wire entries, or the terminal
+        fallback's own verdicts."""
         # Client-side backoff inside an open pushback window: one
         # bounded wait (≤ backoff_max) before dispatching more load
         # at a fleet that is actively shedding this client's tenants.

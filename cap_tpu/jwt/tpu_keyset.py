@@ -1257,11 +1257,12 @@ class TPUBatchKeySet(KeySet):
                 mask[idxs] = pb.payload_object_ok(idxs)
                 pb._raw_ok = mask
         if packed_parts:
-            import jax.numpy as jnp
-
             with telemetry.span(telemetry.SPAN_KEYSET_LAUNCH):
-                flat_dev = (jnp.concatenate(packed_parts)
-                            if len(packed_parts) > 1 else packed_parts[0])
+                # one host copy per chunk, all started now and joined on
+                # the host below: a device concatenate would build a
+                # program for every new mix of chunk sizes, mid-traffic
+                for part in packed_parts:
+                    part.copy_to_host_async()
             # Overlap the host-side claims parsing with the device
             # drain (transfers + compute are still in flight; only
             # np.asarray below truly blocks). Every ok-status token
@@ -1271,7 +1272,7 @@ class TPUBatchKeySet(KeySet):
                 with telemetry.span("claims.prefetch"):
                     pb.prefetch_claims(np.nonzero(ok)[0])
             with telemetry.span(telemetry.SPAN_KEYSET_WAIT):
-                flat = np.asarray(flat_dev)
+                flat = np.concatenate([np.asarray(p) for p in packed_parts])
             with telemetry.span(telemetry.SPAN_KEYSET_FINISH):
                 off = 0
                 for n_slots, consume in packed_meta:

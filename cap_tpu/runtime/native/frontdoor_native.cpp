@@ -210,6 +210,7 @@ struct FdHandle {
   // re-push never un-trips a breaker or forgets in-flight work
   std::atomic<int32_t> live[MAX_POOLS];
   std::atomic<int64_t> inflight[MAX_POOLS];
+  std::atomic<int64_t> pool_tokens[MAX_POOLS];  // relayed, lifetime
   std::atomic<bool> stop{false};
   std::atomic<int64_t> live_threads{0};
   std::mutex conns_mu;
@@ -227,6 +228,7 @@ struct FdHandle {
     for (auto& c : ctr) c.store(0);
     for (auto& l : live) l.store(1);
     for (auto& f : inflight) f.store(0);
+    for (auto& t : pool_tokens) t.store(0);
   }
 };
 
@@ -580,6 +582,8 @@ static void relay_frame(const std::shared_ptr<FdConn>& c,
     else
       h->ctr[FDC_RELAYS].fetch_add(1);
     h->ctr[FDC_RELAY_TOKENS].fetch_add((int64_t)group_idx[g].size());
+    h->pool_tokens[o].fetch_add((int64_t)group_idx[g].size(),
+                                std::memory_order_relaxed);
   }
 }
 
@@ -910,6 +914,15 @@ int64_t cap_frontdoor_inflight(void* hv, int32_t pool_id) {
   FdHandle* h = (FdHandle*)hv;
   if (pool_id < 0 || pool_id >= MAX_POOLS) return 0;
   return h->inflight[pool_id].load(std::memory_order_relaxed);
+}
+
+// Tokens the fast path has relayed to one pool (lifetime): the
+// binding folds them into frontdoor.pool.<id>.tokens beside the
+// slow path's own count.
+int64_t cap_frontdoor_pool_tokens(void* hv, int32_t pool_id) {
+  FdHandle* h = (FdHandle*)hv;
+  if (pool_id < 0 || pool_id >= MAX_POOLS) return 0;
+  return h->pool_tokens[pool_id].load(std::memory_order_relaxed);
 }
 
 // The parity pin: the exact owner decision the relay fast path makes

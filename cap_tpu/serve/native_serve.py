@@ -106,7 +106,7 @@ _FD_SYMBOLS = ("cap_frontdoor_create", "cap_frontdoor_destroy",
                "cap_frontdoor_set_live", "cap_frontdoor_add_conn",
                "cap_frontdoor_drain", "cap_frontdoor_post_raw",
                "cap_frontdoor_counter", "cap_frontdoor_inflight",
-               "cap_frontdoor_probe_route")
+               "cap_frontdoor_pool_tokens", "cap_frontdoor_probe_route")
 
 # exemplar record stride (telemetry_native.h EX_STRIDE)
 _EX_STRIDE = 88
@@ -323,6 +323,9 @@ def _setup_fd(lib: ctypes.CDLL) -> bool:
     lib.cap_frontdoor_inflight.restype = ctypes.c_int64
     lib.cap_frontdoor_inflight.argtypes = [ctypes.c_void_p,
                                            ctypes.c_int32]
+    lib.cap_frontdoor_pool_tokens.restype = ctypes.c_int64
+    lib.cap_frontdoor_pool_tokens.argtypes = [ctypes.c_void_p,
+                                              ctypes.c_int32]
     lib.cap_frontdoor_probe_route.restype = ctypes.c_int32
     lib.cap_frontdoor_probe_route.argtypes = [
         ctypes.c_void_p, _u8p, ctypes.c_int32, _i32p]

@@ -141,7 +141,8 @@ import json
 import socket
 import struct
 import zlib
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import (Any, Callable, Dict, List, NamedTuple, Optional,
+                    Sequence, Tuple)
 
 MAGIC = 0x31425643
 T_VERIFY_REQ = 1
@@ -279,18 +280,35 @@ def send_request(sock: socket.socket, tokens: Sequence[str],
     sock.sendall(b"".join(parts))
 
 
+class RawVerdict(NamedTuple):
+    """One verify-response entry as a worker put it on the wire:
+    ``status`` (0 verified, 1 rejected) and its ``payload`` bytes. A
+    relay that answers with these writes the worker's own bytes back,
+    whatever route the token took."""
+
+    status: int
+    payload: bytes
+
+
+def raw_verdict(r: Any) -> RawVerdict:
+    """The wire entry of one ``verify_batch`` result: claims (a dict,
+    or the raw payload-JSON bytes, sent verbatim) or an Exception
+    (``"<ErrorClass>: <message>"``); a :class:`RawVerdict` is already
+    one."""
+    if isinstance(r, RawVerdict):
+        return r
+    if isinstance(r, Exception):
+        return RawVerdict(1, f"{type(r).__name__}: {r}".encode())
+    if isinstance(r, (bytes, bytearray, memoryview)):
+        return RawVerdict(0, bytes(r))
+    return RawVerdict(0, json.dumps(r, separators=(",", ":")).encode())
+
+
 def _response_parts(ftype: int, results: Sequence[Any]) -> List[bytes]:
     parts = [_HDR.pack(MAGIC, ftype, len(results))]
     for r in results:
-        if isinstance(r, Exception):
-            payload = f"{type(r).__name__}: {r}".encode()
-            parts.append(struct.pack("<BI", 1, len(payload)))
-        elif isinstance(r, (bytes, bytearray, memoryview)):
-            payload = bytes(r)
-            parts.append(struct.pack("<BI", 0, len(payload)))
-        else:
-            payload = json.dumps(r, separators=(",", ":")).encode()
-            parts.append(struct.pack("<BI", 0, len(payload)))
+        status, payload = raw_verdict(r)
+        parts.append(_LEN_BU32.pack(status, len(payload)))
         parts.append(payload)
     return parts
 
@@ -298,17 +316,10 @@ def _response_parts(ftype: int, results: Sequence[Any]) -> List[bytes]:
 def send_response(sock: socket.socket, results: Sequence[Any],
                   crc: bool = False, trace: Optional[str] = None) -> None:
     """results: claims (dict, or the raw payload-JSON bytes the worker
-    verified — sent verbatim, zero re-serialization) or Exception.
-    trace: echo of the request's trace id (traced frame, type 10)."""
-    if trace is not None:
-        parts = _response_parts(T_VERIFY_RESP_TRACE, results)
-        parts.insert(1, _trace_field(trace))
-        _with_crc(parts)
-    elif crc:
-        parts = _with_crc(_response_parts(T_VERIFY_RESP_CRC, results))
-    else:
-        parts = _response_parts(T_VERIFY_RESP, results)
-    sock.sendall(b"".join(parts))
+    verified — sent verbatim, zero re-serialization), Exception, or a
+    :class:`RawVerdict` (written as it is). trace: echo of the
+    request's trace id (traced frame, type 10)."""
+    sock.sendall(encode_response(results, crc=crc, trace=trace))
 
 
 def encode_response(results: Sequence[Any], crc: bool = False,

@@ -28,6 +28,7 @@ from cap_tpu.fleet import ConsistentHashRing, FrontDoor
 from cap_tpu.fleet.frontdoor import (NativeFrontDoorServer,
                                      native_frontdoor_enabled)
 from cap_tpu.fleet.worker_main import StubKeySet
+from cap_tpu.jwt.keyset import KeySet
 from cap_tpu.serve import protocol as P
 from cap_tpu.serve import vcache as V
 from cap_tpu.serve.worker import VerifyWorker
@@ -446,3 +447,357 @@ def test_worker_main_gateway_chain_selection():
             except subprocess.TimeoutExpired:
                 p.kill()
         w0.close(5)
+
+
+# ---------------------------------------------------------------------------
+# one answer per token, whatever the route: the slow path relays the
+# owning worker's own bytes, as the native splice does
+# ---------------------------------------------------------------------------
+
+
+class _KidKeySet(KeySet):
+    """A CPU verifier that routes by ``kid``: a kid it does not hold is
+    an ``UnknownKeyIDError``, so every reject kind below has its own
+    error class on the wire."""
+
+    def __init__(self, keys):
+        self._keys = dict(keys)
+
+    def verify_signature(self, token):
+        from cap_tpu.errors import UnknownKeyIDError
+        from cap_tpu.jwt.jose import parse_jws
+        from cap_tpu.jwt.verify import verify_parsed
+
+        parsed = parse_jws(token)
+        key = self._keys.get(parsed.kid)
+        if key is None:
+            raise UnknownKeyIDError("no key matches kid")
+        verify_parsed(parsed, key)
+        return parsed.claims()
+
+
+def _seeded_tokens(seed=0x9068, n_ok=24):
+    """Keys from the seed; ``n_ok`` accepts, then one token of each
+    reject kind: a forged signature, ``alg=none`` and an unknown kid."""
+    from cryptography.hazmat.primitives.asymmetric import ec
+
+    from cap_tpu.testing import b64url_encode, sign_jwt
+
+    priv = ec.derive_private_key(seed, ec.SECP256R1())
+    other = ec.derive_private_key(seed + 1, ec.SECP256R1())
+    claims = {"iss": "https://as.example.com/", "aud": "rs",
+              "client_id": "c1", "iat": 1767225600, "exp": 2082758400}
+    ok = [sign_jwt(priv, "ES256", dict(claims, sub=f"u{i}", jti=f"j{i}"),
+                   kid="as-2026a") for i in range(n_ok)]
+    h, p, sig = ok[0].split(".")
+    forged = f"{h}.{p}.{sig[:-4]}AAAA"
+    none = (b64url_encode(json.dumps({"alg": "none", "typ": "at+jwt"})
+                          .encode()) + "." + p + ".")
+    unknown = sign_jwt(other, "ES256", dict(claims, sub="x", jti="x"),
+                       kid="as-2099z")
+    keyset = _KidKeySet({"as-2026a": priv.public_key()})
+    return ok + [forged, none, unknown], keyset
+
+
+def _kid_workers(keyset, n=2, **kw):
+    return [VerifyWorker(keyset, target_batch=64, max_wait_ms=1.0, **kw)
+            for _ in range(n)]
+
+
+def _ask(address, tokens, crc=False):
+    s = socket.create_connection(address, timeout=10.0)
+    s.settimeout(30.0)
+    try:
+        P.send_request(s, tokens, crc=crc)
+        return P.FrameReader(s).recv_frame()
+    finally:
+        s.close()
+
+
+def _direct(fd, workers, tokens):
+    """Each token asked alone of the worker that owns it on the ring."""
+    out = []
+    for t in tokens:
+        owner = fd._ring.primary(V.token_digest(t))
+        out.append(_ask(workers[owner].address, [t])[1][0])
+    return out
+
+
+def test_slow_path_answers_with_the_owning_workers_bytes():
+    """Accepts, a forged signature, ``alg=none`` and an unknown kid:
+    the slow path's answer for each token is, byte for byte, what the
+    native splice relays and what the owning worker answers directly —
+    in a frame drained to Python, and in frames that a dead pool sends
+    there for real (``slow.upstream_fail``, then ``slow.dead_pool``)."""
+    tokens, keyset = _seeded_tokens()
+    workers = _kid_workers(keyset)
+    gw = gw_dead = None
+    try:
+        fd = FrontDoor([[w.address] for w in workers])
+        gw = _gateway(fd)
+        want = _direct(fd, workers, tokens)
+        assert [e[0] for e in want] == [0] * 24 + [1, 1, 1]
+        heads = [e[1].split(b":")[0] for e in want[24:]]
+        assert b"RemoteVerifyError" not in heads
+        assert heads[2] == b"UnknownKeyIDError"
+
+        # the native fast path: one token a frame, spliced verbatim
+        s, r = _connect(gw)
+        spliced = []
+        for t in tokens:
+            P.send_request(s, [t])
+            spliced.append(r.recv_frame()[1][0])
+        s.close()
+        assert spliced == want
+        assert gw.native_counters()["frontdoor.native.slow_frames"] == 0
+
+        # one frame drained to the slow path, for each frame family
+        for ftype in (P.T_VERIFY_REQ, P.T_VERIFY_REQ_CRC):
+            frame = _frame(tokens, crc=ftype == P.T_VERIFY_REQ_CRC)
+            resp = gw._handle_slow(frame, ftype, len(tokens))
+            _ft, entries, _tr, _c = P.parse_frame_bytes(resp)
+            assert entries == want, ftype
+
+        # a dead third pool: the frames leave the fast path for real
+        with socket.socket() as probe:
+            probe.bind(("127.0.0.1", 0))
+            dead_port = probe.getsockname()[1]
+        fd_dead = FrontDoor([[w.address] for w in workers]
+                            + [[("127.0.0.1", dead_port)]],
+                            client_kw={"attempt_timeout": 1.0,
+                                       "total_deadline": 5.0,
+                                       "max_rounds": 1,
+                                       "breaker_threshold": 1,
+                                       "breaker_reset_s": 60.0})
+        gw_dead = _gateway(fd_dead)
+        s, r = _connect(gw_dead)
+        for rep in range(4):
+            P.send_request(s, tokens)
+            ftype, entries = r.recv_frame()
+            assert ftype == P.T_VERIFY_RESP
+            assert entries == want, rep
+            time.sleep(0.12)          # breaker → set_live settles
+        s.close()
+        c = gw_dead.stats()["counters"]
+        assert c.get("frontdoor.native.slow.upstream_fail", 0) > 0
+        assert c.get("frontdoor.native.slow.dead_pool", 0) > 0
+        assert c["frontdoor.slow.raw_entries"] == 4 * len(tokens)
+    finally:
+        for g in (gw, gw_dead):
+            if g is not None:
+                g.close(deadline_s=5.0)
+        for w in workers:
+            w.close(5)
+
+
+def _frame(tokens, crc=False):
+    """A verify request frame's bytes, as a client sends them."""
+    class _Sink:
+        data = b""
+
+        def sendall(self, b):
+            self.data += b
+
+    sink = _Sink()
+    P.send_request(sink, tokens, crc=crc)
+    return sink.data
+
+
+def test_slow_path_relays_a_throttled_reject_as_the_worker_sent_it():
+    """Admission sheds on the workers: the slow path's answer carries
+    the worker's own ``ThrottledError`` entry, byte for byte, and the
+    front door still counts it as throttled."""
+    from cap_tpu.fleet.router import FleetClient
+
+    rec = telemetry.enable()
+    rec.reset()
+    tokens, keyset = _seeded_tokens(n_ok=12)
+    workers = _kid_workers(keyset, serve_native=False,
+                           admit_rate=1e-4, admit_burst=2)
+    gw = None
+    sent = []
+    real = FleetClient.verify_batch_wire
+
+    def spy(self, toks):
+        out = real(self, toks)
+        sent.extend(out)
+        return out
+
+    try:
+        fd = FrontDoor([[w.address] for w in workers])
+        gw = _gateway(fd)
+        FleetClient.verify_batch_wire = spy
+        resp = gw._handle_slow(_frame(tokens), P.T_VERIFY_REQ,
+                               len(tokens))
+        _ft, entries, _tr, _c = P.parse_frame_bytes(resp)
+        assert sorted(entries) == sorted(tuple(v) for v in sent)
+        thr = [e for e in entries if e[1].startswith(b"ThrottledError: ")]
+        # each worker's tenant bucket admits two, then sheds
+        assert len(thr) == len(tokens) - 4
+        assert all(e[0] == 1 for e in thr)
+        assert fd.counters()["frontdoor.throttled_tokens"] == len(thr)
+    finally:
+        FleetClient.verify_batch_wire = real
+        if gw is not None:
+            gw.close(deadline_s=5.0)
+        for w in workers:
+            w.close(5)
+        telemetry.disable()
+
+
+def test_slow_path_rejects_classify_by_their_own_reason():
+    """``decision.classify`` reads a reject that came through the slow
+    path by the worker's reason, and so does the front door's own
+    decision fold."""
+    from cap_tpu.obs import decision
+    from cap_tpu.serve.client import RemoteVerifyError
+
+    rec = telemetry.enable()
+    rec.reset()
+    tokens, keyset = _seeded_tokens(n_ok=4)
+    workers = _kid_workers(keyset)
+    gw = None
+    try:
+        fd = FrontDoor([[w.address] for w in workers])
+        gw = _gateway(fd)
+        resp = gw._handle_slow(_frame(tokens), P.T_VERIFY_REQ,
+                               len(tokens))
+        _ft, entries, _tr, _c = P.parse_frame_bytes(resp)
+        reasons = [decision.classify(RemoteVerifyError(e[1].decode()))
+                   for e in entries[4:]]
+        assert reasons[0] == decision.REASON_BAD_SIGNATURE
+        assert reasons[2] == decision.REASON_UNKNOWN_KID
+        assert decision.REASON_INTERNAL not in reasons
+        c = rec.counters()
+        assert c.get("decision.frontdoor.accept") == 4
+        for reason in set(reasons):
+            assert c.get(f"decision.frontdoor.reject.{reason}") \
+                == reasons.count(reason), reason
+        assert not any(k.startswith("decision.frontdoor.reject.internal")
+                       for k in c)
+    finally:
+        if gw is not None:
+            gw.close(deadline_s=5.0)
+        for w in workers:
+            w.close(5)
+        telemetry.disable()
+
+
+def test_frontdoor_verify_batch_still_returns_claims_and_exceptions():
+    """Python callers keep their contract: claims dicts and exceptions,
+    the same verdicts ``verify_batch_wire`` carries as wire entries; the
+    terminal fallback encodes its own verdicts as a worker does."""
+    from cap_tpu.fleet.router import decode_verdict
+    from cap_tpu.serve.client import RemoteVerifyError
+
+    tokens, keyset = _seeded_tokens(n_ok=4)
+    workers = _kid_workers(keyset)
+    try:
+        fd = FrontDoor([[w.address] for w in workers])
+        want = _direct(fd, workers, tokens)
+        got = fd.verify_batch(tokens)
+        assert [type(g) for g in got] == \
+            [dict] * 4 + [RemoteVerifyError] * 3
+        assert got[:4] == [decode_verdict(P.RawVerdict(*e))
+                           for e in want[:4]]
+        assert [str(g).encode() for g in got[4:]] == \
+            [e[1] for e in want[4:]]
+        assert [tuple(v) for v in fd.verify_batch_wire(tokens)] == want
+        fd.close()
+    finally:
+        for w in workers:
+            w.close(5)
+    # every pool gone: the fallback answers in the workers' encoding
+    with socket.socket() as probe:
+        probe.bind(("127.0.0.1", 0))
+        dead = probe.getsockname()[1]
+    fd = FrontDoor([[("127.0.0.1", dead)]], fallback=keyset,
+                   client_kw={"attempt_timeout": 1.0, "max_rounds": 1,
+                              "total_deadline": 2.0})
+    try:
+        assert [tuple(v) for v in fd.verify_batch_wire(tokens)] == want
+        assert fd.counters()["frontdoor.fallback_tokens"] == len(tokens)
+    finally:
+        fd.close()
+
+
+def test_pool_token_counters_sum_to_the_tokens_relayed():
+    """``frontdoor.pool.<id>.tokens`` counts what each pool was sent on
+    either path: the native relay's slot folded into the slow path's
+    count, each pool exactly its ring share, the sum every token."""
+    tokens, keyset = _seeded_tokens(n_ok=40)
+    workers = _kid_workers(keyset, n=3)
+    gw = None
+    try:
+        fd = FrontDoor([[w.address] for w in workers])
+        gw = _gateway(fd)
+        owned = [0] * 3
+        for t in tokens:
+            owned[fd._ring.primary(V.token_digest(t))] += 1
+        s, r = _connect(gw)
+        P.send_request(s, tokens)            # fast path, split by owner
+        r.recv_frame()
+        for t in tokens[:5]:
+            P.send_request(s, [t])           # fast path, spliced
+            r.recv_frame()
+        s.close()
+        gw._handle_slow(_frame(tokens), P.T_VERIFY_REQ, len(tokens))
+        nc = gw.native_counters()
+        assert nc["frontdoor.native.slow_frames"] == 0
+        c = gw.stats()["counters"]
+        per = [c.get(f"frontdoor.pool.{i}.tokens", 0) for i in range(3)]
+        first = [0] * 3
+        for t in tokens[:5]:
+            first[fd._ring.primary(V.token_digest(t))] += 1
+        assert per == [2 * o + f for o, f in zip(owned, first)]
+        assert sum(per) == nc["frontdoor.native.relay_tokens"] \
+            + c["frontdoor.slow.raw_entries"] == 2 * len(tokens) + 5
+    finally:
+        if gw is not None:
+            gw.close(deadline_s=5.0)
+        for w in workers:
+            w.close(5)
+
+
+def test_stats_frame_does_not_wait_behind_slow_path_verify_frames():
+    """A STATS frame drained after a slow-path verify frame is answered
+    while that frame still waits on its pool, and its counters show the
+    frame drained (``slow.overload``) but not yet answered
+    (``slow.raw_entries`` 0); the verify frame's answer then has the
+    bytes the native relay gave the same tokens."""
+    workers = [VerifyWorker(StubKeySet(batch_ms=800), target_batch=64,
+                            max_wait_ms=1.0) for _ in range(2)]
+    gw = None
+    try:
+        # a bounded-load constant so small that an owner with anything
+        # in flight sends the next frame to the slow path
+        fd = FrontDoor([[w.address] for w in workers], spill_factor=0.01)
+        gw = _gateway(fd)
+        tokens = [f"t{i}.ok" for i in range(16)] + ["bad.sig"]
+        sa, ra = _connect(gw)
+        sb, rb = _connect(gw)
+        ss, rs = _connect(gw)
+        P.send_request(sa, tokens)           # native relay, held 0.8 s
+        time.sleep(0.15)
+        P.send_request(sb, tokens)           # owners busy: slow path
+        time.sleep(0.15)
+        t0 = time.monotonic()
+        P.send_stats_request(ss)
+        ftype, entries = rs.recv_frame()
+        waited = time.monotonic() - t0
+        assert ftype == P.T_STATS_RESP
+        c = json.loads(entries[0][1])["counters"]
+        assert c.get("frontdoor.native.slow.overload") == 1
+        assert c.get("frontdoor.slow.raw_entries") == 0
+        assert waited < 0.5, waited
+        fast, slow = ra.recv_frame()[1], rb.recv_frame()[1]
+        assert slow == fast and [e[0] for e in slow] == [0] * 16 + [1]
+        assert gw.stats()["counters"]["frontdoor.slow.raw_entries"] \
+            == len(tokens)
+        for s in (sa, sb, ss):
+            s.close()
+    finally:
+        if gw is not None:
+            gw.close(deadline_s=5.0)
+        for w in workers:
+            w.close(5)

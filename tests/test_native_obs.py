@@ -295,6 +295,10 @@ def test_parity_sweep_tenant_attribution():
     # the flood hit the bounded table: "other" traffic was folded and
     # the exact equation held through BOTH folds (checked per batch)
     assert decision.TENANTS.size() == decision.TENANT_CAP
+    # the table is process-global: leave it empty for the tests that
+    # run after this one in the same process
+    decision._HDR_CACHE.clear()
+    decision.TENANTS.reset()
 
 
 @needs_tel
