@@ -129,6 +129,38 @@ def _route(pb, idx: np.ndarray, kid_to_row: dict, single_key: bool,
     return idx[fast], rows[fast].astype(np.int32)
 
 
+class _Fold:
+    """One batch's verdict fold, alive while _collect_batch runs and
+    handed to each chunk's _finish_arrays: where the answers go, raw
+    mode's phase-1 object mask (None in dict mode), the tokens the fold
+    answered, and of those the accepts whose payload bytes are written
+    in one pass once every chunk is folded (``bulk``)."""
+
+    __slots__ = ("pb", "results", "raw_ok", "answered", "bulk")
+
+    def __init__(self, pb, results: List[Any],
+                 raw_ok: Optional[np.ndarray]) -> None:
+        self.pb, self.results, self.raw_ok = pb, results, raw_ok
+        self.answered = np.zeros(pb.n, bool)
+        self.bulk = np.zeros(pb.n, bool)
+
+
+def _token_families(pb, ok: np.ndarray) -> List[str]:
+    """The decision family of every token of a batch: one take from
+    the interned algs' families by ``alg_id``, "unknown" where prep
+    failed; a non-interned alg (ML-DSA et al., ``alg_id < 0``)
+    classifies from its raw bytes, one token at a time."""
+    from ..runtime.native_binding import ALG_NAMES
+
+    table = np.array([_decision.family_for_alg(a) for a in ALG_NAMES]
+                     + ["unknown"], dtype=object)
+    aid = pb.alg_id
+    fams = table[np.where(ok & (aid >= 0), aid, len(table) - 1)].tolist()
+    for j in np.flatnonzero(ok & (aid < 0)).tolist():
+        fams[j] = _decision.family_for_alg(pb.alg(j))
+    return fams
+
+
 # JAX's duration events for one program traced / built (or loaded from
 # the persistent cache); both carry the function's name as fun_name.
 _JAXPR_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
@@ -1165,7 +1197,7 @@ class TPUBatchKeySet(KeySet):
         # families' H2D transfers (docs/PERF.md).
         pending: List[tuple] = []
         packed_parts: List[Any] = []      # device [pad] bool arrays
-        packed_meta: List[tuple] = []     # (n_slots, consume(arrs))
+        packed_meta: List[tuple] = []     # (n_slots, consume(arrs, fold))
         stats = {"h2d": 0}                # record bytes this batch
         alg_ids = {name: i for i, name in enumerate(ALG_NAMES)}
 
@@ -1180,7 +1212,7 @@ class TPUBatchKeySet(KeySet):
             with _occupancy.interval("rsa", dispatch=False):
                 self._run_rsa_packed("rs", _RS[alg_name], idx, pb,
                                      packed_parts, packed_meta, pending,
-                                     slow, results, stats, tables)
+                                     slow, stats, tables)
 
         def run_ps(alg_name: str, idx: np.ndarray) -> None:
             # Every PS* family rides the packed single-transfer path
@@ -1190,20 +1222,18 @@ class TPUBatchKeySet(KeySet):
             with _occupancy.interval("rsa", dispatch=False):
                 self._run_rsa_packed("ps", _PS[alg_name], idx, pb,
                                      packed_parts, packed_meta,
-                                     pending, slow, results, stats,
-                                     tables)
+                                     pending, slow, stats, tables)
 
         def run_es(alg_name: str, idx: np.ndarray) -> None:
             with _occupancy.interval("ec", dispatch=False):
                 self._run_ec_packed(alg_name, idx, pb, packed_parts,
-                                    packed_meta, pending, slow, results,
-                                    stats, tables)
+                                    packed_meta, pending, slow, stats,
+                                    tables)
 
         def run_ed(alg_name: str, idx: np.ndarray) -> None:
             with _occupancy.interval("ed", dispatch=False):
                 self._run_ed_packed(idx, pb, packed_parts, packed_meta,
-                                    pending, slow, results, stats,
-                                    tables)
+                                    pending, slow, stats, tables)
 
         # Post-quantum first: the deepest device programs (the
         # SLH-DSA hash forest, then the ML-DSA NTT network) go on the
@@ -1247,6 +1277,7 @@ class TPUBatchKeySet(KeySet):
         packed_meta = state["packed_meta"]
 
         raw = state.get("raw", False)
+        mask = None
         if raw:
             # Raw mode replaces dict building with the phase-1-only
             # object check; the mask drives _finish_arrays for the
@@ -1255,7 +1286,7 @@ class TPUBatchKeySet(KeySet):
                 idxs = np.nonzero(ok)[0]
                 mask = np.zeros(n, bool)
                 mask[idxs] = pb.payload_object_ok(idxs)
-                pb._raw_ok = mask
+        fold = _Fold(pb, results, mask)
         if packed_parts:
             with telemetry.span(telemetry.SPAN_KEYSET_LAUNCH):
                 # one host copy per chunk, all started now and joined on
@@ -1280,18 +1311,24 @@ class TPUBatchKeySet(KeySet):
                     for sz in n_slots:
                         arrs.append(flat[off:off + sz])
                         off += sz
-                    consume(arrs)
+                    consume(arrs, fold)
         for chunk, m, fin in pending:
             with telemetry.span(telemetry.SPAN_KEYSET_WAIT):
                 okv = fin()[:m]
             with telemetry.span(telemetry.SPAN_KEYSET_FINISH):
-                self._finish_arrays(chunk, okv, pb, results)
+                self._finish_arrays(chunk, okv, fold)
 
-        # families without device tables (or EC/Ed engines not built):
         with telemetry.span(telemetry.SPAN_KEYSET_FINISH):
+            # the bulk accepts' payload bytes, one pass for the batch
+            acc = np.flatnonzero(fold.bulk)
+            if len(acc):
+                for j, pay in zip(acc.tolist(), pb.payloads(acc)):
+                    results[j] = pay
+            # families without device tables (or EC/Ed engines not
+            # built): the ok tokens the fold left without a verdict
             slow_set = set(slow)
-            for j in range(n):
-                if ok[j] and results[j] is None and j not in slow_set:
+            for j in np.flatnonzero(ok & ~fold.answered).tolist():
+                if results[j] is None:
                     slow_set.add(j)
 
         if slow_set:
@@ -1314,27 +1351,21 @@ class TPUBatchKeySet(KeySet):
         # Device-surface decision records: families come straight from
         # the prep arrays (no token re-parsing on the hot path).
         if telemetry.active() is not None:
-            from ..runtime.native_binding import ALG_NAMES
-
-            fam_for = [_decision.family_for_alg(a) for a in ALG_NAMES]
-            alg_id = pb.alg_id
-
-            def fam(j: int) -> str:
-                if not ok[j]:
-                    return "unknown"
-                aid = int(alg_id[j])
-                if aid >= 0:
-                    return fam_for[aid]
-                # non-interned algs (ML-DSA et al.) carry raw bytes
-                return _decision.family_for_alg(pb.alg(j))
-
             with telemetry.span(telemetry.SPAN_KEYSET_DECISIONS):
-                fams = [fam(j) for j in range(n)]
+                # a reject is a token the fold did not accept in bulk:
+                # a prep error or special, a one-at-a-time answer, or
+                # the CPU fallback's
+                may_reject = ~fold.bulk
+                if slow_set:
+                    may_reject[list(slow_set)] = True
+                rejects = [j for j in np.flatnonzero(may_reject).tolist()
+                           if isinstance(results[j], BaseException)]
                 t_dispatch = state.get("t_dispatch")
                 _decision.record_batch(
-                    "tpu", results, families=fams,
+                    "tpu", results, families=_token_families(pb, ok),
                     latency_s=(time.perf_counter() - t_dispatch
-                               if t_dispatch is not None else None))
+                               if t_dispatch is not None else None),
+                    rejects=rejects)
         # Close the batch's dispatch-level busy interval: dispatch
         # start → collect end is the window this batch held device
         # work in flight (the occupancy numerator).
@@ -1371,38 +1402,49 @@ class TPUBatchKeySet(KeySet):
         self._wire_bps = est if prev is None else 0.5 * prev + 0.5 * est
 
     @staticmethod
-    def _finish_arrays(chunk, okv, pb, results: List[Any]) -> None:
-        """Write per-token verdicts for one array-path device chunk.
+    def _finish_arrays(chunk, okv, fold: _Fold) -> None:
+        """Fold the verdicts of one device chunk into the batch's answers.
 
-        Raw mode (``pb._raw_ok`` set by _collect_batch): accepted
-        tokens yield their payload BYTES; a verified signature over a
-        non-object payload raises through claims() so the error object
-        is byte-identical to the dict path's.
+        Raw mode (``fold.raw_ok`` set): the accepts whose payload
+        passed the phase-1 object check are marked in ``fold.bulk``;
+        _collect_batch writes their payload BYTES in one pass. The rest
+        go one at a time, counted in ``keyset.finish.per_token``: a
+        reject gets its InvalidSignatureError; a verified signature
+        over a payload the check flagged goes through claims(), so the
+        error object is byte-identical to the dict path's. Dict mode
+        answers every token one at a time, from the claims cache.
         """
-        raw_ok = getattr(pb, "_raw_ok", None)
+        pb, results, raw_ok = fold.pb, fold.results, fold.raw_ok
+        chunk = np.asarray(chunk)
+        okv = np.asarray(okv).astype(bool, copy=False)
+        fold.answered[chunk] = True
+        if raw_ok is not None:
+            bulk = okv & raw_ok[chunk]
+            fold.bulk[chunk[bulk]] = True
+            chunk, okv = chunk[~bulk], okv[~bulk]
+        if len(chunk) == 0:
+            return
+        telemetry.count("keyset.finish.per_token", len(chunk))
         cache = getattr(pb, "_claims_cache", None)
         if cache is None:
             cache = {}
         claims = pb.claims
         msg = ("no known key successfully validated the token "
                "signature")
-        for j, good in zip(np.asarray(chunk).tolist(),
-                           np.asarray(okv).tolist()):
-            if good:
-                if raw_ok is not None:
-                    if raw_ok[j]:
-                        results[j] = pb.payload_bytes(j)
-                    else:
-                        # The phase-1 mask is only a FAST FILTER:
-                        # json.loads stays authoritative (it accepts
-                        # e.g. BOM-prefixed payloads the strict scan
-                        # flags), exactly like the dict path.
-                        try:
-                            claims(j)
-                            results[j] = pb.payload_bytes(j)
-                        except MalformedTokenError as e:
-                            results[j] = e
-                    continue
+        for j, good in zip(chunk.tolist(), okv.tolist()):
+            if not good:
+                results[j] = InvalidSignatureError(msg)
+            elif raw_ok is not None:
+                # The phase-1 mask is only a FAST FILTER: json.loads
+                # stays authoritative (it accepts e.g. BOM-prefixed
+                # payloads the strict scan flags), exactly like the
+                # dict path.
+                try:
+                    claims(j)
+                    results[j] = pb.payload_bytes(j)
+                except MalformedTokenError as e:
+                    results[j] = e
+            else:
                 hit = cache.get(j)
                 if hit is None:
                     try:
@@ -1410,8 +1452,6 @@ class TPUBatchKeySet(KeySet):
                     except MalformedTokenError as e:
                         hit = e
                 results[j] = hit
-            else:
-                results[j] = InvalidSignatureError(msg)
 
     def _chunk_tokens(self, rec_width: int) -> int:
         """Tokens per packed chunk, pow-2 for shape reuse.
@@ -1438,8 +1478,7 @@ class TPUBatchKeySet(KeySet):
                         packed_parts: List[Any],
                         packed_meta: List[tuple],
                         pending: List[tuple],
-                        slow: List[int], results: List[Any],
-                        stats: dict,
+                        slow: List[int], stats: dict,
                         tables: Optional[_KeyTables] = None) -> None:
         from ..tpu import rsa as tpursa
 
@@ -1483,8 +1522,8 @@ class TPUBatchKeySet(KeySet):
                                         mesh=self._mesh)
                 packed_parts.append(ok_dev)
 
-                def consume(arrs, chunk=chunk, m=m):
-                    self._finish_arrays(chunk, arrs[0][:m], pb, results)
+                def consume(arrs, fold, chunk=chunk, m=m):
+                    self._finish_arrays(chunk, arrs[0][:m], fold)
 
                 packed_meta.append(([pad], consume))
 
@@ -1492,8 +1531,7 @@ class TPUBatchKeySet(KeySet):
                        packed_parts: List[Any],
                        packed_meta: List[tuple],
                        pending: List[tuple],
-                       slow: List[int], results: List[Any],
-                       stats: dict,
+                       slow: List[int], stats: dict,
                        tables: Optional[_KeyTables] = None) -> None:
         from ..tpu import ec as tpuec
         from ..tpu.rsa import HASH_LEN
@@ -1532,8 +1570,9 @@ class TPUBatchKeySet(KeySet):
             packed_parts.append(ok_dev)
             packed_parts.append(deg_dev)
 
-            def consume(arrs, chunk=chunk, m=m, rec=rec, crows=crows,
-                        table=table, cb=cb, hash_len=hash_len):
+            def consume(arrs, fold, chunk=chunk, m=m, rec=rec,
+                        crows=crows, table=table, cb=cb,
+                        hash_len=hash_len):
                 okv = np.array(arrs[0][:m])
                 deg = arrs[1][:m]
                 for j in np.nonzero(deg)[0]:
@@ -1541,7 +1580,7 @@ class TPUBatchKeySet(KeySet):
                         table, int(crows[j]),
                         rec[j, : 2 * cb].tobytes(),
                         rec[j, 2 * cb: 2 * cb + hash_len].tobytes())
-                self._finish_arrays(chunk, okv, pb, results)
+                self._finish_arrays(chunk, okv, fold)
 
             packed_meta.append(([pad, pad], consume))
 
@@ -1734,8 +1773,7 @@ class TPUBatchKeySet(KeySet):
                        packed_parts: List[Any],
                        packed_meta: List[tuple],
                        pending: List[tuple],
-                       slow: List[int], results: List[Any],
-                       stats: dict,
+                       slow: List[int], stats: dict,
                        tables: Optional[_KeyTables] = None) -> None:
         from ..tpu import ed25519 as tpued
 
@@ -1773,8 +1811,8 @@ class TPUBatchKeySet(KeySet):
                         table, rec, mesh=self._mesh)
             packed_parts.append(ok_dev)
 
-            def consume(arrs, chunk=chunk, m=m):
-                self._finish_arrays(chunk, arrs[0][:m], pb, results)
+            def consume(arrs, fold, chunk=chunk, m=m):
+                self._finish_arrays(chunk, arrs[0][:m], fold)
 
             packed_meta.append(([pad], consume))
 

@@ -41,6 +41,7 @@ import base64
 import binascii
 import hashlib
 import json
+import re
 import threading
 from collections import Counter
 from typing import Any, Dict, List, Optional, Sequence
@@ -519,6 +520,8 @@ SURFACES = ("oracle", "tpu", "serve", "router", "frontdoor")
 RING_SAMPLE_EVERY = 16
 
 _MAX_FIELD_LEN = 64
+# a character matches iff str.isspace() holds for it
+_SPACE = re.compile(r"\s")
 
 
 def _checked_entry(entry: Dict[str, Any]) -> Dict[str, Any]:
@@ -527,7 +530,7 @@ def _checked_entry(entry: Dict[str, Any]) -> Dict[str, Any]:
     the same stance as :func:`telemetry.check_name`."""
     for k, v in entry.items():
         if isinstance(v, str) and ("eyJ" in v or len(v) > _MAX_FIELD_LEN
-                                   or any(ch.isspace() for ch in v)):
+                                   or _SPACE.search(v)):
             raise ValueError(
                 f"decision field {k!r} rejected by redaction rules")
     return entry
@@ -537,13 +540,17 @@ def record_batch(surface: str, results: Sequence[Any],
                  tokens: Optional[Sequence[Any]] = None,
                  families: Optional[Sequence[str]] = None,
                  latency_s: Optional[float] = None,
-                 trace: Optional[str] = None) -> None:
+                 trace: Optional[str] = None,
+                 rejects: Optional[Sequence[int]] = None) -> None:
     """Fold one batch of per-token verdicts into decision telemetry.
 
     results: the verify_batch contract — claims dict / raw payload
     bytes per accept, Exception per reject. tokens OR families supply
     the per-token family ("unknown" when neither is available, e.g.
-    stub engines). No-op (one attribute check) while telemetry is off.
+    stub engines). rejects, when the caller already knows them: the
+    index of every Exception in results, so the walk over all results
+    is skipped (the counters and the ring come out the same). No-op
+    (one attribute check) while telemetry is off.
     """
     rec = telemetry.active()
     if rec is None or not results:
@@ -559,18 +566,21 @@ def record_batch(surface: str, results: Sequence[Any],
     # SAMPLE POSITIONS are identical to k single-token walks (sampled
     # counts are c == 1 or c % RING_SAMPLE_EVERY == 0 over the same
     # post-increment sequence, attributed to the same token).
+    if rejects is None:
+        rejects = [i for i, r in enumerate(results)
+                   if isinstance(r, BaseException)]
+    # the accepts are the runs between the sorted rejects (all-accept:
+    # no index list materialized — sampling indexes a range)
     reject_groups: Dict[str, List[int]] = {}
-    if any(isinstance(r, BaseException) for r in results):
-        accept_idx: Any = []
-        for i, res in enumerate(results):
-            if isinstance(res, BaseException):
-                reject_groups.setdefault(classify(res), []).append(i)
-            else:
-                accept_idx.append(i)
-    else:
-        # all-accept fast path (the raw-claims serve hot loop): no
-        # index list materialized — sampling indexes a range
-        accept_idx = range(len(results))
+    accept_idx: Any = range(len(results))
+    if len(rejects):
+        accept_idx = []
+        lo = 0
+        for i in sorted(rejects):
+            reject_groups.setdefault(classify(results[i]), []).append(i)
+            accept_idx.extend(range(lo, i))
+            lo = i + 1
+        accept_idx.extend(range(lo, len(results)))
 
     n_results = len(results)
     ten_counts: Counter = Counter()

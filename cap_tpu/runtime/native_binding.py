@@ -315,7 +315,7 @@ class PreparedBatch:
     __slots__ = ("n", "status", "alg_id", "kid_mat", "kid_len", "sig_off",
                  "sig_len", "payload_off", "payload_len", "si_len", "digest",
                  "digest_len", "scratch", "blob", "tok_off", "alg_raw",
-                 "alg_len", "_claims_cache", "_raw_ok")
+                 "alg_len", "_claims_cache")
 
     def __init__(self, n, status, alg_id, kid_mat, kid_len, sig_off, sig_len,
                  payload_off, payload_len, si_len, digest, digest_len,
@@ -423,6 +423,15 @@ class PreparedBatch:
     def payload_bytes(self, i: int) -> bytes:
         o, l = int(self.payload_off[i]), int(self.payload_len[i])
         return self.scratch[o: o + l].tobytes()
+
+    def payloads(self, idx) -> List[bytes]:
+        """The payload bytes of the tokens ``idx``, in order: one
+        ``tobytes()`` of ``scratch``, then one slicing pass (no
+        per-token numpy slice or int conversion)."""
+        buf = self.scratch.tobytes()
+        starts = self.payload_off[idx]
+        return [buf[o:e] for o, e in zip(
+            starts.tolist(), (starts + self.payload_len[idx]).tolist())]
 
     def payload_object_ok(self, indices) -> np.ndarray:
         """[len(indices)] bool: the payload parses as a JSON OBJECT.

@@ -163,6 +163,16 @@ def test_checked_entry_rejects_token_material():
         decision._checked_entry({"reason": "a" * 100})
 
 
+def test_checked_entry_space_rule_is_str_isspace():
+    """The redaction check's whitespace rule rejects exactly the
+    characters str.isspace() calls whitespace, over all of Unicode."""
+    for cp in range(0x110000):
+        ch = chr(cp)
+        assert bool(decision._SPACE.search(f"a{ch}b")) == ch.isspace(), cp
+    with pytest.raises(ValueError):
+        decision._checked_entry({"family": "r\u2003s"})
+
+
 def test_counter_names_pass_redaction_check():
     """Every counter key the layer can emit survives check_name."""
     for surface in decision.SURFACES:

@@ -209,6 +209,64 @@ def test_record_batch_tenant_none_paths():
         assert c["tenant.attributed"] == 3
 
 
+def _mixed_verdicts(n: int):
+    """n verdicts: accepts (dicts and raw bytes) and rejects of four
+    reason classes, at irregular positions."""
+    from cap_tpu.errors import MalformedTokenError, TokenNotSignedError
+
+    out = []
+    for i in range(n):
+        if i % 7 == 3:
+            out.append(InvalidSignatureError("bad sig"))
+        elif i % 11 == 5:
+            out.append(ExpiredTokenError("expired"))
+        elif i % 13 == 0:
+            out.append(MalformedTokenError("malformed"))
+        elif i % 29 == 9:
+            out.append(TokenNotSignedError("unsigned"))
+        else:
+            out.append({"s": i} if i % 2 else b'{"s":1}')
+    return out
+
+
+@pytest.mark.parametrize("per_token", ["tokens", "families"])
+def test_record_batch_given_rejects_matches_the_walk(per_token):
+    """record_batch given the reject indices counts and samples the
+    ring exactly as its own walk over the results: every counter, and
+    every ring entry at the same position, over consecutive batches
+    (so the sample positions depend on the running counts)."""
+    batches = [_mixed_verdicts(n) for n in (300, 41, 517, 1)]
+    batches.append([{"s": 1}] * 40)                 # no reject at all
+    batches.append([InvalidSignatureError("x")] * 20)   # no accept
+
+    def fold(given: bool):
+        decision._HDR_CACHE.clear()
+        decision.TENANTS.reset()
+        with telemetry.recording() as rec:
+            for results in batches:
+                kw = {}
+                if per_token == "tokens":
+                    kw["tokens"] = [tenant_token(
+                        f"https://t{i % 3}.example",
+                        alg=("RS256", "ES256")[i % 2])
+                        for i in range(len(results))]
+                else:
+                    kw["families"] = [("rs", "es", "unknown")[i % 3]
+                                      for i in range(len(results))]
+                if given:
+                    kw["rejects"] = [i for i, r in enumerate(results)
+                                     if isinstance(r, Exception)]
+                decision.record_batch("tpu", results, latency_s=0.002,
+                                      **kw)
+            return rec.counters(), rec.decisions()
+
+    walked, given = fold(False), fold(True)
+    assert any(k.startswith("decision.tpu.reject.") for k in walked[0])
+    assert given[0] == walked[0]
+    assert given[1] == walked[1]
+    assert len(walked[1]) > 40
+
+
 def test_record_batch_tenant_counters_all_surfaces():
     tok = tenant_token("https://s.example", kid="ks")
     h = decision.token_tenant(tok)

@@ -448,3 +448,224 @@ def test_payload_object_ok_matches_json_loads():
     # and for these plain-UTF-8 payloads the mask is exact
     assert [bool(g) for g in got] == [
         True, False, False, False, False, True, True, True, True, True]
+
+
+def _per_token_finish(chunk, okv, fold):
+    """The verdict fold one token at a time, as it was before the
+    vectorized fold: the reference the fold is held to. It marks no
+    token answered or bulk, so the collect's scan for missing verdicts
+    and its reject list then cover every token."""
+    pb, results, raw_ok = fold.pb, fold.results, fold.raw_ok
+    cache = getattr(pb, "_claims_cache", None)
+    if cache is None:
+        cache = {}
+    claims = pb.claims
+    msg = ("no known key successfully validated the token "
+           "signature")
+    for j, good in zip(np.asarray(chunk).tolist(),
+                       np.asarray(okv).tolist()):
+        if good:
+            if raw_ok is not None:
+                if raw_ok[j]:
+                    results[j] = pb.payload_bytes(j)
+                else:
+                    try:
+                        claims(j)
+                        results[j] = pb.payload_bytes(j)
+                    except MalformedTokenError as e:
+                        results[j] = e
+                continue
+            hit = cache.get(j)
+            if hit is None:
+                try:
+                    hit = claims(j)
+                except MalformedTokenError as e:
+                    hit = e
+            results[j] = hit
+        else:
+            results[j] = InvalidSignatureError(msg)
+
+
+def _per_token_families(pb, ok):
+    from cap_tpu.obs import decision
+    from cap_tpu.runtime.native_binding import ALG_NAMES
+
+    fam_for = [decision.family_for_alg(a) for a in ALG_NAMES]
+    out = []
+    for j in range(pb.n):
+        if not ok[j]:
+            out.append("unknown")
+        elif int(pb.alg_id[j]) >= 0:
+            out.append(fam_for[int(pb.alg_id[j])])
+        else:
+            out.append(decision.family_for_alg(pb.alg(j)))
+    return out
+
+
+def _fold_mix():
+    """One batch with every kind of answer the fold gives, and the
+    index of each crafted token by name: RS256 and ES256 accepts,
+    flipped signatures, a swapped payload, alg=none, a malformed
+    segment, verified signatures over a non-object, a non-JSON and a
+    BOM-prefixed payload, JSON-form tokens (one compacted onto the
+    device path, one whose kid rides only in the unprotected header,
+    verified on the object path, and one that does not parse), a kid
+    no key row holds (the CPU fallback), and an ES384 and an HS256
+    token, which no device table takes (the scan for missing
+    verdicts)."""
+    jwks, signers = captest.headline_keys()
+    toks = captest.sign_unique_jwts(signers, 40)
+    es_priv, es_pub = captest.generate_keys("ES256")
+    ks = TPUBatchKeySet(jwks + [JWK(es_pub, kid="raw-es")])
+
+    def flip(tok):
+        h, p, s = tok.split(".")
+        return f"{h}.{p}.{s[:5]}{'A' if s[5] != 'A' else 'B'}{s[6:]}"
+
+    h0, _, s0 = toks[0].split(".")
+    priv, alg, kid = signers[0]
+    p384, _ = captest.generate_keys("ES384")
+    crafted = {
+        "flipped_rs": flip(toks[1]),
+        "flipped_es": flip(toks[8]),
+        "swapped": f"{h0}.{toks[2].split('.')[1]}.{s0}",
+        "alg_none": (b64url_encode(b'{"alg":"none"}') + "."
+                     + toks[4].split(".")[1] + "."),
+        "malformed": f"{h0}.@@@.{s0}",
+        "array": _sign_raw_payload(es_priv, "ES256", b"[1,2,3]",
+                                   "raw-es"),
+        "not_json": _sign_raw_payload(es_priv, "ES256", b"{not json",
+                                      "raw-es"),
+        "bom": _sign_raw_payload(es_priv, "ES256",
+                                 b'\xef\xbb\xbf{"b":1}', "raw-es"),
+        "json_compacted": captest.to_json_form(
+            toks[6], unprotected={"x5u": "u"}),
+        "json_special": captest.to_json_form(
+            captest.sign_jwt(priv, alg, captest.default_claims()),
+            unprotected={"kid": kid}),
+        "json_broken": '{"payload": "e30", "signatures": 5}',
+        "flipped_late": flip(toks[11]),
+        "unknown_kid": captest.sign_jwt(
+            priv, alg, captest.default_claims(), kid="not-in-the-jwks"),
+        "es384": captest.sign_jwt(p384, "ES384", captest.default_claims(),
+                                  kid="es384-x"),
+        "hs256": (b64url_encode(b'{"alg":"HS256","kid":"h"}') + "."
+                  + b64url_encode(b'{"a":1}') + "."
+                  + b64url_encode(b"x" * 32)),
+    }
+    at = {name: len(toks) + i for i, name in enumerate(crafted)}
+    return ks, toks + list(crafted.values()), at
+
+
+def _same_answer(a, b):
+    if isinstance(a, Exception):
+        return (type(a) is type(b) and str(a) == str(b)
+                and a.args == b.args)
+    return type(a) is type(b) and a == b
+
+
+def test_vectorized_fold_matches_per_token_reference(monkeypatch):
+    """Raw and dict mode: every answer of the vectorized verdict fold
+    equals the per-token reference's, byte for byte and error class
+    and message for message; so do the CPU fallback's count and every
+    tpu-surface decision counter and ring entry. ES lanes flagged
+    degenerate are re-verified on the CPU and enter the fold the
+    same way."""
+    import jax.numpy as jnp
+
+    from cap_tpu import telemetry
+    from cap_tpu.jwt import tpu_keyset as tk
+    from cap_tpu.obs import decision
+    from cap_tpu.tpu import ec as tpuec
+
+    ks, batch, at = _fold_mix()
+    real_es = tpuec.verify_es_packed_pending
+    deg_calls = []
+
+    def es_with_degenerate_lanes(*a, **kw):
+        ok_dev, deg_dev = real_es(*a, **kw)
+        return ok_dev, jnp.asarray(deg_dev).at[::3].set(True)
+
+    real_cpu = tpuec._cpu_verify_one
+
+    def spy(*a):
+        deg_calls.append(a[1])
+        return real_cpu(*a)
+
+    monkeypatch.setattr(tpuec, "verify_es_packed_pending",
+                        es_with_degenerate_lanes)
+    monkeypatch.setattr(tpuec, "_cpu_verify_one", spy)
+
+    def run(reference: bool):
+        with monkeypatch.context() as m:
+            if reference:
+                real_record = decision.record_batch
+
+                def walk(*a, rejects=None, **kw):
+                    return real_record(*a, **kw)
+
+                m.setattr(TPUBatchKeySet, "_finish_arrays",
+                          staticmethod(_per_token_finish))
+                m.setattr(tk, "_token_families", _per_token_families)
+                m.setattr(decision, "record_batch", walk)
+            out = {}
+            for mode in ("raw", "dict"):
+                with telemetry.recording() as rec:
+                    res = (ks.verify_batch_raw(batch) if mode == "raw"
+                           else ks.verify_batch(batch))
+                    c = rec.counters()
+                    ring = [{k: v for k, v in e.items() if k != "lat"}
+                            for e in rec.decisions()]
+                out[mode] = (res, {k: v for k, v in c.items()
+                                   if k.startswith("decision.")
+                                   or k == "cpu_fallback.tokens"}, ring)
+            return out
+
+    want, got = run(True), run(False)
+    assert deg_calls, "no ES lane was flagged degenerate"
+    for mode in ("raw", "dict"):
+        (w, wc, wr), (g, gc, gr) = want[mode], got[mode]
+        assert len(g) == len(w) == len(batch)
+        bad = [i for i, (a, b) in enumerate(zip(w, g))
+               if not _same_answer(a, b)]
+        assert not bad, (mode, [(w[i], g[i]) for i in bad])
+        assert gc == wc, mode
+        assert gr == wr, mode
+    raw, dicts = got["raw"][0], got["dict"][0]
+    # unknown_kid, es384, hs256
+    assert got["raw"][1]["cpu_fallback.tokens"] == 3
+    # the crafted tokens answer as the kinds they were made to be
+    for name in ("flipped_rs", "flipped_es", "swapped", "flipped_late",
+                 "es384", "hs256"):
+        assert isinstance(raw[at[name]], InvalidSignatureError), name
+    for name in ("alg_none", "malformed", "array", "not_json",
+                 "json_broken"):
+        assert isinstance(raw[at[name]], Exception), name
+    for name in ("malformed", "array", "not_json", "json_broken"):
+        assert isinstance(raw[at[name]], MalformedTokenError), name
+    assert raw[at["bom"]] == b'\xef\xbb\xbf{"b":1}'
+    for name in ("bom", "json_compacted", "json_special", "unknown_kid"):
+        assert isinstance(raw[at[name]], bytes), name
+        assert isinstance(dicts[at[name]], dict), name
+    assert sum(isinstance(r, bytes) for r in raw) == 40 + 4
+
+
+def test_finish_per_token_counts_the_tokens_answered_one_by_one():
+    """``keyset.finish.per_token``: in raw mode the device-path rejects
+    and the verified signatures whose payload the phase-1 check
+    flagged; in dict mode every token the fold answers. Prep errors
+    and JSON-form specials never reach the fold."""
+    from cap_tpu import telemetry
+
+    ks, batch, _ = _fold_mix()
+    with telemetry.recording() as rec:
+        ks.verify_batch_raw(batch)
+        raw = rec.counters().get("keyset.finish.per_token", 0)
+    with telemetry.recording() as rec:
+        ks.verify_batch(batch)
+        dicts = rec.counters().get("keyset.finish.per_token", 0)
+    # 3 flipped + 1 swapped signature; [1,2,3], {not json, BOM
+    assert raw == 4 + 3
+    # the 40 signed tokens, the 7 crafted ones that pass prep and the
+    # compacted JSON form
+    assert dicts == 40 + 7 + 1
